@@ -116,11 +116,10 @@ def run(quick: bool = True):
     from repro.core.baselines.fedavg import _fedavg_scan_plan
     from repro.core.sweep import run_sweep
     from repro.launch.mesh import make_federation_mesh
-    from repro.sharding.fed import resolve_mesh
 
     rows = []
-    mesh = make_federation_mesh(2, 4)
-    sharded = resolve_mesh(mesh) is not None  # False on < 8 devices
+    sharded = jax.device_count() >= 8
+    mesh = make_federation_mesh(2, 4) if sharded else None
     n = 32
     rounds = 6 if quick else 24
     task = _population_task(n, 1024 if quick else 4096)

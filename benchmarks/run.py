@@ -20,6 +20,9 @@ def _speedup(derived: str) -> float | None:
 
 
 def main() -> None:
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="paper-scale settings (hours on CPU); default is reduced")

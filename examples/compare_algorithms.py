@@ -18,6 +18,9 @@ from repro.models.classifier import make_classifier
 
 
 def main():
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--lam", type=float, default=0.3, help="Dirichlet concentration")
     ap.add_argument("--dataset", default="mnist", choices=["mnist", "cifar10", "cifar100"])

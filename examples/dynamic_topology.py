@@ -17,6 +17,9 @@ from repro.models.classifier import make_classifier
 
 
 def main():
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     ds = make_dataset("mnist", train_size=4000, test_size=1000, seed=0)
     clients = dirichlet_partition(ds.train_y, 20, 0.6, seed=0)
     clusters = assign_clusters(20, 5, seed=0)

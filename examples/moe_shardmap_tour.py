@@ -21,6 +21,9 @@ import argparse
 
 
 def main():
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="deepseek-v3-671b",
                     choices=["deepseek-v3-671b", "dbrx-132b"])

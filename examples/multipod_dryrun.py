@@ -17,6 +17,9 @@ from repro.roofline.analysis import analyze_compiled, roofline_terms
 
 
 def main():
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b", choices=list(ARCH_IDS))
     args = ap.parse_args()

@@ -19,6 +19,9 @@ from repro.netsim import edge_cloud_network, simulate_run, time_to_accuracy
 
 
 def main():
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     # -- 1. a small non-IID task and two recorded training runs ------------
     ds = make_dataset("mnist", train_size=3000, test_size=600, seed=0)
     clients = dirichlet_partition(ds.train_y, num_clients=20, alpha=0.6, seed=0)

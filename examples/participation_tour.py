@@ -21,6 +21,9 @@ from repro.part import AvailabilityAware, GilbertElliottTrace
 
 
 def main() -> None:
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     ds = make_dataset("mnist", train_size=3000, test_size=800, seed=0)
     clients = dirichlet_partition(ds.train_y, 15, 0.6, seed=0)
     clusters = assign_clusters(15, 5, seed=0)

@@ -8,6 +8,9 @@ from repro.models.classifier import make_classifier
 
 
 def main():
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     # 1. data: 20 clients with Dirichlet(0.6) label skew, 4 ES clusters
     ds = make_dataset("mnist", train_size=4000, test_size=1000, seed=0)
     clients = dirichlet_partition(ds.train_y, num_clients=20, alpha=0.6, seed=0)

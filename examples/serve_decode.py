@@ -17,6 +17,9 @@ from repro.models import transformer as tf
 
 
 def main():
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b", choices=list(ARCH_IDS))
     ap.add_argument("--batch", type=int, default=4, help="concurrent requests")

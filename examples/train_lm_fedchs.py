@@ -77,6 +77,9 @@ def _resolve_arch(name: str):
 
 
 def main():
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default=None, metavar="ARCH",
                     help="registry architecture id (e.g. qwen3_0_6b); "

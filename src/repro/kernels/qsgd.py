@@ -12,12 +12,19 @@ TPU adaptation notes (vs the CPU/GPU reference implementations of QSGD):
 
 The fused quantize→pack / unpack→dequantize pair emits/consumes the packed
 uint32 wire format defined (bit-for-bit) by `ref.pack_codes_ref`: sign-folded
-codes, bit-plane packed, b = ceil(log2(2s+1)) bits per entry.  The pack
-reduction runs over the *sublane* axis of a (rows, 32, W) view — every word
-sums 32 single-bit terms at distinct bit positions, so a uint32 add is an
-exact bitwise OR — keeping the lane axis contiguous for the VPU.  `s` and
-`bits` are static closure args (functools.partial), not scalar operands, so
-the per-bit loop unrolls at trace time.
+codes, bit-plane packed, b = ceil(log2(2s+1)) bits per entry.  The kernels
+never split or merge the lane axis: the wrappers hand them (rows, 32, W)
+views of the blocks and (rows, b, W) views of the payload (free row-major
+reshapes outside the kernel), so the pack is a reduction over the *sublane*
+axis — every word sums 32 single-bit terms at distinct bit positions, so an
+integer add is an exact bitwise OR.  Mosaic has no unsigned reductions and
+no float→uint32 cast, so all code/word math runs in int32 (two's complement
+makes the bit patterns identical) and the wrappers bitcast to the uint32
+wire dtype.  Block norms are computed by the wrapper with the oracle's own
+jnp expression and enter the quantize kernel as (rows, 1, 1) blocks, so the
+norms sidecar equals `ref.py` bit for bit on every backend.  `s` and `bits`
+are static closure args (functools.partial), not scalar operands, so the
+per-bit loop unrolls at trace time.
 
 All wrappers accept any n_blocks: tail tiles are handled by host-side
 pad-to-ROWS_PER_TILE + slice (padding rows are all-zero -> zero norms -> the
@@ -61,20 +68,20 @@ def _quantize_kernel(v_ref, u_ref, s_ref, q_ref, n_ref):
     v = v_ref[...]  # (rows, block) f32
     u = u_ref[...]
     s = s_ref[0]  # scalar f32 (levels)
-    norms = jnp.sqrt(jnp.sum(v * v, axis=1))  # (rows,)
+    norms = jnp.sqrt(jnp.sum(v * v, axis=1, keepdims=True))  # (rows, 1)
     safe = jnp.where(norms > 0, norms, 1.0)
-    p = jnp.abs(v) / safe[:, None] * s
+    p = jnp.abs(v) / safe * s
     q = jnp.clip(jnp.floor(p + u), 0.0, s)
-    q = jnp.where(norms[:, None] > 0, q, 0.0)
+    q = jnp.where(norms > 0, q, 0.0)
     q_ref[...] = (jnp.sign(v) * q).astype(jnp.int8)
-    n_ref[...] = norms.astype(jnp.float32)
+    n_ref[...] = norms
 
 
 def _dequantize_kernel(q_ref, n_ref, s_ref, v_ref):
     q = q_ref[...].astype(jnp.float32)
     norms = n_ref[...]
     s = s_ref[0]
-    v_ref[...] = q * (norms[:, None] / s)
+    v_ref[...] = q * (norms / s)  # norms (rows, 1)
 
 
 def _interpret() -> bool:
@@ -101,15 +108,15 @@ def qsgd_quantize_blocks(
         ],
         out_specs=[
             pl.BlockSpec((rows_per_tile, block), lambda i: (i, 0)),
-            pl.BlockSpec((rows_per_tile,), lambda i: (i,)),
+            pl.BlockSpec((rows_per_tile, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((padded, block), jnp.int8),
-            jax.ShapeDtypeStruct((padded,), jnp.float32),
+            jax.ShapeDtypeStruct((padded, 1), jnp.float32),
         ],
         interpret=_interpret(),
     )(v, u, s_arr)
-    return q[:n_blocks], norms[:n_blocks]
+    return q[:n_blocks], norms[:n_blocks, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("s", "rows_per_tile"))
@@ -126,13 +133,13 @@ def qsgd_dequantize_blocks(
         grid=grid,
         in_specs=[
             pl.BlockSpec((rows_per_tile, block), lambda i: (i, 0)),
-            pl.BlockSpec((rows_per_tile,), lambda i: (i,)),
+            pl.BlockSpec((rows_per_tile, 1), lambda i: (i, 0)),
             pl.BlockSpec((1,), lambda i: (0,)),
         ],
         out_specs=pl.BlockSpec((rows_per_tile, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((padded, block), jnp.float32),
         interpret=_interpret(),
-    )(q, norms, s_arr)
+    )(q, norms[:, None], s_arr)
     return out[:n_blocks]
 
 
@@ -141,53 +148,56 @@ def qsgd_dequantize_blocks(
 # --------------------------------------------------------------------------
 
 
-def _pack_words(codes: jnp.ndarray, bits: int) -> jnp.ndarray:
-    """(rows, block) uint32 codes -> (rows, bits * block/32) uint32 payload.
+def _pack_planes(codes: jnp.ndarray, bits: int) -> jnp.ndarray:
+    """(rows, 32, W) int32 codes -> (rows, bits, W) int32 words.
 
-    Layout defined by `ref.pack_codes_ref`.  The (rows, 32, W) view puts the
-    32 codes of a word on the sublane axis; each plane word is a 32-term sum
-    of single bits at distinct positions (an exact OR in uint32 arithmetic).
-    """
+    Layout defined by `ref.pack_codes_ref`: the 32 codes of a word sit on the
+    sublane axis; plane word j sums bit j of each at its own position (an
+    exact OR; bit 31 wraps to the sign bit, the same 32-bit pattern)."""
+    pos = jax.lax.broadcasted_iota(jnp.int32, codes.shape, 1)
+    return jnp.concatenate(
+        [jnp.sum(((codes >> j) & 1) << pos, axis=1, keepdims=True)
+         for j in range(bits)], axis=1)
+
+
+def _unpack_planes(words: jnp.ndarray, bits: int) -> jnp.ndarray:
+    """Exact inverse of `_pack_planes`: (rows, bits, W) -> (rows, 32, W)."""
+    rows, _, w_per_plane = words.shape
+    pos = jax.lax.broadcasted_iota(jnp.int32, (rows, 32, w_per_plane), 1)
+    c = jnp.zeros((rows, 32, w_per_plane), jnp.int32)
+    for j in range(bits):
+        c = c | (((words[:, j:j + 1, :] >> pos) & 1) << j)
+    return c
+
+
+def _pack_words(codes: jnp.ndarray, bits: int) -> jnp.ndarray:
+    """(rows, block) uint32 codes -> (rows, bits * block/32) uint32 payload."""
     rows, block = codes.shape
-    w_per_plane = block // 32
-    c = codes.reshape(rows, 32, w_per_plane)
-    pos = jax.lax.broadcasted_iota(jnp.uint32, (rows, 32, w_per_plane), 1)
-    planes = [
-        jnp.sum(((c >> jnp.uint32(j)) & jnp.uint32(1)) << pos, axis=1, dtype=jnp.uint32)
-        for j in range(bits)
-    ]
-    return jnp.concatenate(planes, axis=1)
+    planes = _pack_planes(codes.astype(jnp.int32).reshape(rows, 32, block // 32), bits)
+    return jax.lax.bitcast_convert_type(planes, jnp.uint32).reshape(rows, -1)
 
 
 def _unpack_words(payload: jnp.ndarray, bits: int) -> jnp.ndarray:
     """Exact inverse of `_pack_words`: (rows, bits*W) uint32 -> (rows, 32*W)."""
     rows = payload.shape[0]
-    w_per_plane = payload.shape[1] // bits
-    pos = jax.lax.broadcasted_iota(jnp.uint32, (rows, 32, w_per_plane), 1)
-    c = jnp.zeros((rows, 32, w_per_plane), jnp.uint32)
-    for j in range(bits):
-        word = jax.lax.slice_in_dim(payload, j * w_per_plane, (j + 1) * w_per_plane, axis=1)
-        c = c | (((word[:, None, :] >> pos) & jnp.uint32(1)) << jnp.uint32(j))
-    return c.reshape(rows, 32 * w_per_plane)
+    words = jax.lax.bitcast_convert_type(payload, jnp.int32).reshape(rows, bits, -1)
+    return _unpack_planes(words, bits).astype(jnp.uint32).reshape(rows, -1)
 
 
-def _quantize_pack_kernel(v_ref, u_ref, payload_ref, n_ref, *, s: int, bits: int):
-    v = v_ref[...]  # (rows, block) f32
-    u = u_ref[...]
-    norms = jnp.sqrt(jnp.sum(v * v, axis=1))
+def _quantize_pack_kernel(v_ref, u_ref, n_ref, payload_ref, *, s: int, bits: int):
+    v = v_ref[...]  # (rows, 32, W) f32
+    norms = n_ref[...]  # (rows, 1, 1) f32
     safe = jnp.where(norms > 0, norms, 1.0)
-    p = jnp.abs(v) / safe[:, None] * s
-    q = jnp.clip(jnp.floor(p + u), 0.0, float(s))
-    q = jnp.where(norms[:, None] > 0, q, 0.0)
-    codes = (jnp.sign(v) * q + s).astype(jnp.uint32)  # sign-folded, in [0, 2s]
-    payload_ref[...] = _pack_words(codes, bits)
-    n_ref[...] = norms.astype(jnp.float32)
+    p = jnp.abs(v) / safe * s
+    q = jnp.clip(jnp.floor(p + u_ref[...]), 0.0, float(s))
+    q = jnp.where(norms > 0, q, 0.0)
+    codes = (jnp.sign(v) * q + s).astype(jnp.int32)  # sign-folded, in [0, 2s]
+    payload_ref[...] = _pack_planes(codes, bits)
 
 
 def _unpack_dequantize_kernel(payload_ref, n_ref, v_ref, *, s: int, bits: int):
-    codes = _unpack_words(payload_ref[...], bits)
-    q = codes.astype(jnp.int32) - s
-    v_ref[...] = q.astype(jnp.float32) * (n_ref[...][:, None] / s)
+    q = _unpack_planes(payload_ref[...], bits) - s
+    v_ref[...] = q.astype(jnp.float32) * (n_ref[...] / s)
 
 
 @functools.partial(jax.jit, static_argnames=("s", "rows_per_tile"))
@@ -200,27 +210,21 @@ def qsgd_quantize_pack_blocks(
     rows_per_tile = rows_per_tile or _auto_rows(n_blocks)
     assert block % 32 == 0, block
     bits = qsgd_code_bits(s)
-    words = bits * (block // 32)
-    (v, u), padded = _pad_rows([v, u], n_blocks, rows_per_tile)
+    w = block // 32
+    norms = jnp.sqrt(jnp.sum(v * v, axis=1))  # the oracle's expression
+    (v, u, n), padded = _pad_rows([v, u, norms], n_blocks, rows_per_tile)
     grid = (padded // rows_per_tile,)
-    payload, norms = pl.pallas_call(
+    tile = pl.BlockSpec((rows_per_tile, 32, w), lambda i: (i, 0, 0))
+    payload = pl.pallas_call(
         functools.partial(_quantize_pack_kernel, s=s, bits=bits),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((rows_per_tile, block), lambda i: (i, 0)),
-            pl.BlockSpec((rows_per_tile, block), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((rows_per_tile, words), lambda i: (i, 0)),
-            pl.BlockSpec((rows_per_tile,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((padded, words), jnp.uint32),
-            jax.ShapeDtypeStruct((padded,), jnp.float32),
-        ],
+        in_specs=[tile, tile, pl.BlockSpec((rows_per_tile, 1, 1), lambda i: (i, 0, 0))],
+        out_specs=pl.BlockSpec((rows_per_tile, bits, w), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((padded, bits, w), jnp.int32),
         interpret=_interpret(),
-    )(v, u)
-    return payload[:n_blocks], norms[:n_blocks]
+    )(v.reshape(padded, 32, w), u.reshape(padded, 32, w), n.reshape(padded, 1, 1))
+    payload = jax.lax.bitcast_convert_type(payload, jnp.uint32).reshape(padded, bits * w)
+    return payload[:n_blocks], norms
 
 
 @functools.partial(jax.jit, static_argnames=("s", "block", "rows_per_tile"))
@@ -237,18 +241,20 @@ def qsgd_unpack_dequantize_blocks(
     n_blocks = payload.shape[0]
     rows_per_tile = rows_per_tile or _auto_rows(n_blocks)
     bits = qsgd_code_bits(s)
-    assert payload.shape[1] == bits * (block // 32), (payload.shape, bits, block)
+    w = block // 32
+    assert payload.shape[1] == bits * w, (payload.shape, bits, block)
     (payload, norms), padded = _pad_rows([payload, norms], n_blocks, rows_per_tile)
     grid = (padded // rows_per_tile,)
     out = pl.pallas_call(
         functools.partial(_unpack_dequantize_kernel, s=s, bits=bits),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((rows_per_tile, payload.shape[1]), lambda i: (i, 0)),
-            pl.BlockSpec((rows_per_tile,), lambda i: (i,)),
+            pl.BlockSpec((rows_per_tile, bits, w), lambda i: (i, 0, 0)),
+            pl.BlockSpec((rows_per_tile, 1, 1), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((rows_per_tile, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((padded, block), jnp.float32),
+        out_specs=pl.BlockSpec((rows_per_tile, 32, w), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((padded, 32, w), jnp.float32),
         interpret=_interpret(),
-    )(payload, norms)
-    return out[:n_blocks]
+    )(jax.lax.bitcast_convert_type(payload, jnp.int32).reshape(padded, bits, w),
+      norms.reshape(padded, 1, 1))
+    return out.reshape(padded, block)[:n_blocks]

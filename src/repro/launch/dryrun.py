@@ -100,6 +100,9 @@ def run_one(arch: str, shape: str, mesh_kind: str, variant: str, *,
 
 
 def main() -> None:
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, choices=list(ARCH_IDS))
     ap.add_argument("--shape", default=None, choices=list(SHAPES))

@@ -6,59 +6,43 @@ XLA_FLAGS=--xla_force_host_platform_device_count=512 *before* any jax import.
 """
 from __future__ import annotations
 
-import logging
+import math
 
 import jax
 
-_log = logging.getLogger(__name__)
-
 
 def _make_mesh(shape, axes, devices) -> jax.sharding.Mesh:
-    # axis_types / AxisType only exist on newer jax; older versions default
-    # to Auto semantics anyway
-    kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, devices=devices, **kwargs)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
+def _take_devices(n: int, what: str) -> list:
+    """The first n devices, or an error naming the mesh that cannot be built
+    (a smaller mesh would quietly run a multi-chip job on fewer chips)."""
+    if n > jax.device_count():
+        raise RuntimeError(
+            f"{what} needs {n} devices, found {jax.device_count()} "
+            f"({jax.devices()[0].platform})")
+    return jax.devices()[:n]
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """16x16 = 256 chips per pod; multi_pod stacks 2 pods = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = 1
-    for s in shape:
-        n *= s
-    devices = jax.devices()[:n]
-    if len(devices) < n:
-        raise RuntimeError(
-            f"mesh {shape} needs {n} devices, found {len(devices)} — run via "
-            "launch/dryrun.py which sets xla_force_host_platform_device_count"
-        )
+    devices = _take_devices(
+        math.prod(shape), f"mesh {shape} (run via launch/dryrun.py, which sets "
+        "xla_force_host_platform_device_count)")
     return _make_mesh(shape, axes, devices)
 
 
 def make_debug_mesh(data: int = 1, model: int = 1, pod: int | None = None):
-    """Tiny mesh over however many real devices exist (tests).
-
-    Falls back to a single-device mesh (with a logged warning, not an
-    error) when the requested shape exceeds `jax.device_count()`, so
-    examples written against a forced-device count still run on 1-device
-    CPU."""
+    """Tiny mesh over real devices (tests); raises RuntimeError when the
+    requested shape exceeds `jax.device_count()`."""
     shape = (pod, data, model) if pod else (data, model)
     axes = ("pod", "data", "model") if pod else ("data", "model")
-    n = 1
-    for s in shape:
-        n *= s
-    if n > jax.device_count():
-        _log.warning(
-            "debug mesh %s needs %d devices but only %d exist — "
-            "falling back to a single-device mesh",
-            dict(zip(axes, shape)), n, jax.device_count(),
-        )
-        shape = tuple(1 for _ in shape)
-        n = 1
-    return _make_mesh(shape, axes, jax.devices()[:n])
+    devices = _take_devices(math.prod(shape), f"debug mesh {dict(zip(axes, shape))}")
+    return _make_mesh(shape, axes, devices)
 
 
 def make_federation_mesh(clusters: int = 1, clients: int | None = None):
@@ -72,21 +56,13 @@ def make_federation_mesh(clusters: int = 1, clients: int | None = None):
         with model_mesh(make_federation_mesh(clusters=2, clients=4)):
             run_fed_chs(task, config)   # sharded; mesh=None configs adopt it
 
-    Falls back to a single-device mesh with a logged warning when the
-    requested shape exceeds `jax.device_count()` — a mesh=None-equivalent
-    run, never an error."""
+    Raises RuntimeError when the requested shape exceeds
+    `jax.device_count()`."""
     if clients is None:
         clients = max(jax.device_count() // clusters, 1)
-    n = clusters * clients
-    if n > jax.device_count():
-        _log.warning(
-            "federation mesh (clusters=%d, clients=%d) needs %d devices but "
-            "only %d exist — falling back to a single-device mesh",
-            clusters, clients, n, jax.device_count(),
-        )
-        clusters = clients = n = 1
-    return _make_mesh((clusters, clients), ("clusters", "clients"),
-                      jax.devices()[:n])
+    devices = _take_devices(
+        clusters * clients, f"federation mesh (clusters={clusters}, clients={clients})")
+    return _make_mesh((clusters, clients), ("clusters", "clients"), devices)
 
 
 POD_CHIPS = 256

@@ -24,6 +24,9 @@ import time
 
 
 def main() -> None:
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     # Set before any jax BACKEND INIT (jax reads XLA_FLAGS lazily, at first
     # use) — and only on the CLI path: importing this module (e.g. tests
     # pulling in serve_loop) must not force a 512-device partition on the

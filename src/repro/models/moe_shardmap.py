@@ -30,20 +30,6 @@ from repro.configs.base import ArchConfig
 from repro.models.common import activation
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool):
-    # newer jax exposes jax.shard_map(check_vma=...); older only has the
-    # experimental API with the check_rep spelling
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )
-
-
 def shardmap_supported(cfg: ArchConfig, mesh, batch: int) -> bool:
     """Routed-expert shard_map needs divisible shards and a (data, model) mesh."""
     if mesh is None or "data" not in mesh.axis_names or "model" not in mesh.axis_names:
@@ -117,7 +103,7 @@ def moe_routed_shardmap(cfg: ArchConfig, p: dict, x, mesh, *,
         # pad a spec to full rank with Nones on unmentioned (leading) axes
         return P(*spec)
 
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         interior,
         mesh=mesh,
         in_specs=(

@@ -80,24 +80,6 @@ from repro.utils import tree_add, tree_sub
 PyTree = Any
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map (newer jax) with fallback to the experimental module.
-
-    Replication checking is disabled either way: the bodies return
-    all-gathered (hence replicated) values that the checker cannot prove
-    replicated across the un-gathered axis."""
-    try:
-        return jax.shard_map(  # type: ignore[attr-defined]
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
-
-
 def resolve_mesh(mesh: Mesh | None) -> Mesh | None:
     """The federation mesh a driver should shard over, or None.
 
@@ -405,12 +387,16 @@ def sharded_chunk_fn(kind: str, model, channel, es_channel, opt, mesh: Mesh,
     def chunk(carry, xs, consts):
         return jax.lax.scan(lambda c, x: body(c, x, consts), carry, xs)
 
+    # replication checking is off: the bodies return all-gathered (hence
+    # replicated) values the checker cannot prove replicated across the
+    # un-gathered axis
     return _jit_round(
-        _shard_map(
+        jax.shard_map(
             chunk,
             mesh=mesh,
             in_specs=(specs["carry"], xs_specs, P()),
             out_specs=(specs["carry"], specs["ys"]),
+            check_vma=False,
         )
     )
 

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+import os
 from typing import Any, Callable
 
 import jax
@@ -9,6 +10,25 @@ import jax.numpy as jnp
 import numpy as np
 
 PyTree = Any
+
+# the checkout root (src/repro/utils.py -> ../..): a fixed cache path, since
+# the path is part of every cache key and a moving directory never hits
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; call before the first compile.
+
+    `JAX_COMPILATION_CACHE_DIR`, when set, is the cache (JAX reads it itself
+    and nothing is set here); otherwise the cache lives in `.jax_cache` at the
+    root of the checkout.  Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    return CHECKOUT_CACHE_DIR
 
 
 def tree_zeros_like(tree: PyTree) -> PyTree:

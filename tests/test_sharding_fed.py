@@ -285,14 +285,16 @@ def test_single_device_federation_mesh_is_inert():
     _check(r0, r1, exact_loss=True)
 
 
-@pytest.mark.skipif(jax.device_count() >= 8, reason="enough devices exist")
-def test_federation_mesh_falls_back_with_warning(caplog):
-    with caplog.at_level("WARNING", logger="repro.launch.mesh"):
-        m = make_federation_mesh(2, 4)
-    assert m.size == 1
-    assert any("falling back to a single-device mesh" in r.message
-               for r in caplog.records)
-    assert resolve_mesh(m) is None
+def test_federation_mesh_falls_back_with_warning():
+    """Too few devices is an error, never a quiet single-device mesh: a
+    multi-chip run must not silently run on one chip."""
+    from repro.launch.mesh import make_debug_mesh
+
+    n = jax.device_count()
+    with pytest.raises(RuntimeError, match=f"needs {2 * n} devices, found {n}"):
+        make_federation_mesh(2, n)
+    with pytest.raises(RuntimeError, match=f"needs {2 * n} devices, found {n}"):
+        make_debug_mesh(2, n)
 
 
 def test_forced_8_devices_subprocess():
