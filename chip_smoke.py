@@ -55,6 +55,12 @@ from repro.obs.trace import SpanTracer  # noqa: E402
 CUSTOM_CALL = "tpu_custom_call"
 
 
+def check(ok: bool, what) -> None:
+    """Fail the phase (an explicit raise: `assert` is stripped under -O)."""
+    if not ok:
+        raise AssertionError(what)
+
+
 def device_info() -> dict:
     devs = jax.devices()
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
@@ -177,7 +183,7 @@ def phase_kernels(leaf_shape=(1024, 3072), levels=(1, 16, 127), *,
                           "differ from ref.py")
         if d_err > 1e-6 * d_scale:
             faults.append(f"s={s}: decode differs from ref.py by {d_err}")
-    assert not faults, "; ".join(faults)
+    check(not faults, "; ".join(faults))
 
 
 # --------------------------------------------------------------------------
@@ -212,8 +218,8 @@ def phase_paper(task: FLTask | None = None) -> None:
               f"peak_bytes_in_use {peak}", flush=True)
     print(f"paper: acc before training {acc0:.4f}, after {res.test_acc[-1]:.4f}; "
           f"ledger {res.ledger.total_bits()} bits", flush=True)
-    assert all(math.isfinite(x) for x in res.train_loss), res.train_loss
-    assert res.test_acc[-1] > acc0 + 0.2, (acc0, res.test_acc)
+    check(all(math.isfinite(x) for x in res.train_loss), res.train_loss)
+    check(res.test_acc[-1] > acc0 + 0.2, (acc0, res.test_acc))
 
 
 # --------------------------------------------------------------------------
@@ -288,11 +294,11 @@ def phase_lm(cfg: ArchConfig | None = None, *, seq: int = 128,
     # cannot bring it under the vocabulary size.  What the check can hold
     # the round to: no divergence, the held-out loss within one nat of the
     # uniform predictor's ln(vocab).
-    assert all(math.isfinite(x) for x in res.train_loss), res.train_loss
-    assert all(math.isfinite(p) and p < math.e * cfg.vocab_size for p in res.test_acc), \
-        (res.test_acc, cfg.vocab_size)
-    if require_kernel:
-        assert flash, "flash kernel missing from the compiled round program"
+    check(all(math.isfinite(x) for x in res.train_loss), res.train_loss)
+    check(all(math.isfinite(p) and p < math.e * cfg.vocab_size for p in res.test_acc),
+          (res.test_acc, cfg.vocab_size))
+    check(flash or not require_kernel,
+          "flash kernel missing from the compiled round program")
 
 
 # --------------------------------------------------------------------------
@@ -324,10 +330,10 @@ def phase_sharded() -> None:
     print(f"sharded: acc 1-device={one.test_acc} mesh={many.test_acc}", flush=True)
     print(f"sharded: ledger bits 1-device={one.ledger.total_bits()} "
           f"mesh={many.ledger.total_bits()}", flush=True)
-    assert len(devs) == 4, f"mesh holds {len(devs)} devices, not 4"
-    assert one.ledger.total_bits() == many.ledger.total_bits()
-    assert one.ledger.history == many.ledger.history
-    assert same, diffs
+    check(len(devs) == 4, f"mesh holds {len(devs)} devices, not 4")
+    check(one.ledger.total_bits() == many.ledger.total_bits(), "ledger totals differ")
+    check(one.ledger.history == many.ledger.history, "ledger histories differ")
+    check(same, diffs)
 
 
 def main(argv=None) -> None:
