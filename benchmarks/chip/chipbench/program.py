@@ -1,0 +1,186 @@
+"""The system under test, built for one cell: the program's model, task and
+`FedCHSConfig`, with the benchmark's weights and data.
+
+The weights are made by the benchmark, on the device, in one jitted call
+from the seed, in the program's own parameter layout (`jax.eval_shape` of
+the program's init) and in the dtype the configuration trains its masters
+in.  They reach `run_fed_chs` through `SeededModel.init`, so the program
+starts every call from the same weights the reference starts from.
+"""
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from chipbench import traffic
+
+
+# --------------------------------------------------------------------------
+# weights
+# --------------------------------------------------------------------------
+
+
+def _leaf_name(path) -> str:
+    last = path[-1]
+    return str(getattr(last, "key", getattr(last, "name", last)))
+
+
+def _init_leaf(name: str, key, shape, dtype):
+    """The benchmark's init rule: norm weights one, biases zero, the
+    embedding N(0, 0.02^2), every other matrix N(0, 1 / fan_in)."""
+    if name in ("ln1", "ln2", "final_norm", "q_norm", "k_norm"):
+        return jnp.ones(shape, dtype)
+    if name.startswith("b") and len(shape) == 1:
+        return jnp.zeros(shape, dtype)
+    if name == "embed":
+        return (0.02 * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+    w = jax.random.normal(key, shape, jnp.float32) * np.sqrt(1.0 / shape[-2])
+    return w.astype(dtype)
+
+
+@functools.cache
+def _weights_fn(treedef, leaves: tuple):
+    def make(key):
+        out = []
+        for i, (name, shape, dtype) in enumerate(leaves):
+            out.append(_init_leaf(name, jax.random.fold_in(key, i), shape, dtype))
+        return jax.tree.unflatten(treedef, out)
+
+    return jax.jit(make)
+
+
+def weights_maker(like, seed: int):
+    """() -> a fresh weights pytree shaped like `like` (ShapeDtypeStructs)."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(like)
+    leaves = tuple((_leaf_name(p), tuple(x.shape), jnp.dtype(x.dtype)) for p, x in flat)
+    fn = _weights_fn(treedef, leaves)
+    key = jax.random.PRNGKey(traffic.sub_seed(seed, traffic.TAG_WEIGHTS))
+    return lambda: fn(key)
+
+
+# --------------------------------------------------------------------------
+# the program's model, with the benchmark's weights
+# --------------------------------------------------------------------------
+
+
+class Capture:
+    """Host copies of the params the driver evaluates, while `on`."""
+
+    def __init__(self):
+        self.on = False
+        self.params: list = []
+
+
+@dataclasses.dataclass(frozen=True)
+class SeededModel:
+    """A program `FedModel` whose `init` returns the benchmark's weights.
+
+    Equality and hash are the wrapped model's, so every seed in a process
+    reuses the program's compiled rounds."""
+
+    inner: Any
+    make: Any = dataclasses.field(compare=False, hash=False)
+    capture: Capture = dataclasses.field(compare=False, hash=False,
+                                         default_factory=Capture)
+
+    @property
+    def name(self) -> str:
+        return self.inner.name
+
+    @property
+    def metric_name(self) -> str:
+        return self.inner.metric_name
+
+    @property
+    def metric_mode(self) -> str:
+        return self.inner.metric_mode
+
+    def init(self, key):
+        del key
+        return self.make()
+
+    def loss(self, params, batch):
+        return self.inner.loss(params, batch)
+
+    def eval_metric(self, params, eval_data):
+        if self.capture.on:
+            self.capture.params.append(jax.device_get(params))
+        return self.inner.eval_metric(params, eval_data)
+
+
+def arch_config(config: dict):
+    """The program's `ArchConfig` for a decoder LM configuration file."""
+    from repro.configs.base import ArchConfig
+
+    assert config.get("hidden_act", "silu") == "silu"
+    return ArchConfig(
+        name=config["name"], family="dense",
+        num_layers=config["num_hidden_layers"], d_model=config["hidden_size"],
+        num_heads=config["num_attention_heads"],
+        num_kv_heads=config["num_key_value_heads"], head_dim=config["head_dim"],
+        d_ff=config["intermediate_size"], vocab_size=config["vocab_size"],
+        qkv_bias=bool(config.get("attention_bias", False)),
+        qk_norm=True, rope_theta=float(config["rope_theta"]), act="silu",
+        norm_eps=float(config["rms_norm_eps"]),
+        tie_embeddings=bool(config["tie_word_embeddings"]),
+        dtype=config["precision"]["master"])
+
+
+def inner_model(config: dict):
+    if config["family"] != "lm":
+        raise ValueError(f"unknown family {config['family']!r}")
+    from repro.models.fed import LMFedModel
+
+    return LMFedModel(arch_config(config), remat=config["remat"], flash=config["flash"])
+
+
+def precision_policy(config: dict):
+    from repro.core.precision import Precision
+
+    p = config["precision"]
+    return Precision(compute=p["compute"], master=p["master"], wire=p["wire"])
+
+
+def channel(spec: dict):
+    from repro.comm.channels import QSGDChannel
+
+    if spec["kind"] == "dense":
+        return None  # the driver's rule: the policy's wire dtype, else f32
+    if spec["kind"] == "qsgd":
+        return QSGDChannel(spec["levels"])
+    raise ValueError(f"unknown channel {spec}")
+
+
+@dataclasses.dataclass
+class Program:
+    task: Any
+    config: Any           # FedCHSConfig of one call
+    model: SeededModel
+    weights: Any          # () -> the initial weights (a fresh device copy)
+
+
+def build(config: dict, fed: traffic.Federation, seed: int, tracer) -> Program:
+    from repro.core import FedCHSConfig
+    from repro.core.simulation import FLTask
+    from repro.obs import RunTelemetry
+
+    inner = inner_model(config)
+    like = jax.eval_shape(inner.init, jax.random.PRNGKey(0))
+    make = weights_maker(like, seed)
+    model = SeededModel(inner, make)
+    task = FLTask.from_source(model, fed.source, fed.clusters, seed=0)
+    lrs = [float(x) for x in fed.lrs]
+    conf = FedCHSConfig(
+        rounds=fed.rounds, local_steps=fed.local_steps, local_epochs=fed.local_epochs,
+        topology=fed.topology, topology_seed=fed.topology_seed,
+        initial_cluster=fed.initial_cluster, eval_every=fed.eval_every,
+        channel=channel(fed.channel), precision=precision_policy(config),
+        client_microbatch=fed.client_microbatch, schedule=lambda k: lrs[k],
+        seed=fed.program_seed, chunk_rounds=max(32, fed.eval_every),
+        obs=RunTelemetry(taps=False, tracer=tracer))
+    return Program(task, conf, model, make)
