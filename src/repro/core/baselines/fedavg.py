@@ -285,13 +285,14 @@ def _fedavg_scan_plan(task: FLTask, source, config: FedAvgConfig):
 
 def _run_fedavg_scanned(task: FLTask, config: FedAvgConfig) -> RunResult:
     obs = config.obs
-    with maybe_span(obs, "precompute"):
-        plan, params_of, traffic = _fedavg_scan_plan(task, task.source, config)
-    recorder = RunRecorder(task, config.rounds, config.eval_every, obs=obs)
-    carry = run_scan(
-        plan, lambda t, c, losses, _lt: recorder.record(t, params_of(c), losses)
-    )
-    ledger = CommLedger(track_events=config.track_events)
-    with maybe_span(obs, "materialize"):
-        ledger.materialize(traffic(config.track_events))
-    return recorder.result("fedavg", ledger, params_of(carry))
+    with maybe_span(obs, "call"):
+        with maybe_span(obs, "precompute"):
+            plan, params_of, traffic = _fedavg_scan_plan(task, task.source, config)
+        recorder = RunRecorder(task, config.rounds, config.eval_every, obs=obs)
+        carry = run_scan(
+            plan, lambda t, c, losses, _lt: recorder.record(t, params_of(c), losses)
+        )
+        ledger = CommLedger(track_events=config.track_events)
+        with maybe_span(obs, "materialize"):
+            ledger.materialize(traffic(config.track_events))
+        return recorder.result("fedavg", ledger, params_of(carry))

@@ -407,21 +407,22 @@ def _hier_scan_plan(task: FLTask, source, config: HierLocalQSGDConfig):
 
 def _run_hier_scanned(task: FLTask, config: HierLocalQSGDConfig) -> RunResult:
     obs = config.obs
-    with maybe_span(obs, "precompute"):
-        plan, params_of, traffic, sel_of = _hier_scan_plan(task, task.source, config)
-    recorder = RunRecorder(task, config.rounds, config.eval_every, obs=obs)
+    with maybe_span(obs, "call"):
+        with maybe_span(obs, "precompute"):
+            plan, params_of, traffic, sel_of = _hier_scan_plan(task, task.source, config)
+        recorder = RunRecorder(task, config.rounds, config.eval_every, obs=obs)
 
-    def record(t, carry, losses, last_t):
-        if losses is not None:
-            sel = sel_of(last_t)
-            if sel is not None:
-                # the looped driver logs the mean over the clusters that
-                # actually trained in the last trained round
-                losses = losses[:, sel]
-        recorder.record(t, params_of(carry), losses)
+        def record(t, carry, losses, last_t):
+            if losses is not None:
+                sel = sel_of(last_t)
+                if sel is not None:
+                    # the looped driver logs the mean over the clusters that
+                    # actually trained in the last trained round
+                    losses = losses[:, sel]
+            recorder.record(t, params_of(carry), losses)
 
-    carry = run_scan(plan, record)
-    ledger = CommLedger(track_events=config.track_events)
-    with maybe_span(obs, "materialize"):
-        ledger.materialize(traffic(config.track_events))
-    return recorder.result("hier_local_qsgd", ledger, params_of(carry))
+        carry = run_scan(plan, record)
+        ledger = CommLedger(track_events=config.track_events)
+        with maybe_span(obs, "materialize"):
+            ledger.materialize(traffic(config.track_events))
+        return recorder.result("hier_local_qsgd", ledger, params_of(carry))

@@ -234,13 +234,14 @@ def _wrwgd_scan_plan(task: FLTask, source, config: WRWGDConfig):
 
 def _run_wrwgd_scanned(task: FLTask, config: WRWGDConfig) -> RunResult:
     obs = config.obs
-    with maybe_span(obs, "precompute"):
-        plan, params_of, traffic = _wrwgd_scan_plan(task, task.source, config)
-    recorder = RunRecorder(task, config.rounds, config.eval_every, obs=obs)
-    carry = run_scan(
-        plan, lambda t, c, losses, _lt: recorder.record(t, params_of(c), losses)
-    )
-    ledger = CommLedger(track_events=config.track_events)
-    with maybe_span(obs, "materialize"):
-        ledger.materialize(traffic(config.track_events))
-    return recorder.result("wrwgd", ledger, params_of(carry))
+    with maybe_span(obs, "call"):
+        with maybe_span(obs, "precompute"):
+            plan, params_of, traffic = _wrwgd_scan_plan(task, task.source, config)
+        recorder = RunRecorder(task, config.rounds, config.eval_every, obs=obs)
+        carry = run_scan(
+            plan, lambda t, c, losses, _lt: recorder.record(t, params_of(c), losses)
+        )
+        ledger = CommLedger(track_events=config.track_events)
+        with maybe_span(obs, "materialize"):
+            ledger.materialize(traffic(config.track_events))
+        return recorder.result("wrwgd", ledger, params_of(carry))

@@ -1035,7 +1035,10 @@ def _run_chunks(chunk, carry, stage, plan: ScanPlan, record, *, last_slice) -> P
     segment the trained rounds at eval boundaries (capped at `chunk_rounds`),
     stage + `device_put` + execute each chunk, track the last trained round's
     on-device loss row (`last_slice` absorbs the sweep's leading seed axis),
-    and fire `record` at every eval round."""
+    and fire `record` at every eval round.  With `plan.obs` set, each chunk's
+    "stage" span holds a "draw" and a "device_put" span, and the chunk adds
+    its staged bytes and rounds to the `staged_bytes`/`trained_rounds`
+    counters."""
     obs = plan.obs
     tapped = obs is not None and obs.taps
     xs_put = plan.xs_put if plan.xs_put is not None else jax.device_put
@@ -1048,7 +1051,14 @@ def _run_chunks(chunk, carry, stage, plan: ScanPlan, record, *, last_slice) -> P
             take = min(plan.chunk_rounds, n_t - pos)
             idxs = trained_idx[pos : pos + take]
             with maybe_span(obs, "stage"):
-                xs = xs_put(stage(idxs))
+                with maybe_span(obs, "draw"):
+                    staged = stage(idxs)
+                if obs is not None:
+                    obs.count("staged_bytes",
+                              sum(leaf.nbytes for leaf in jax.tree.leaves(staged)))
+                    obs.count("trained_rounds", len(idxs))
+                with maybe_span(obs, "device_put"):
+                    xs = xs_put(staged)
             with maybe_span(obs, "scan_chunk"):
                 carry, ys = chunk(carry, xs, plan.consts)
                 if tapped:

@@ -434,42 +434,77 @@ def _fed_chs_scan_plan(task: FLTask, source, config: FedCHSConfig):
     assert config.local_steps % config.local_epochs == 0, "K must divide by E"
     K, E = config.local_steps, config.local_epochs
     interactions = K // E
-    sched_fn = config.schedule or paper_sqrt_schedule(K, half=False)
-    lrs = np.array([sched_fn(k) for k in range(K)], dtype=np.float32)
-
-    dyn = None
-    if config.dynamic is not None:
-        from repro.core.dynamics import make_dynamic
-
-        dyn = make_dynamic(config.dynamic, task.num_clusters, seed=config.topology_seed)
-        topo = dyn(0)
-    else:
-        topo = make_topology(config.topology, task.num_clusters, seed=config.topology_seed)
-    rng = np.random.default_rng(config.seed)
-    m0 = (
-        int(rng.integers(task.num_clusters))
-        if config.initial_cluster is None
-        else config.initial_cluster
-    )
-    full_part = is_full_participation(config.sampler)
-    scheduler = _make_scheduler(task, config, topo, m0)
-    # visit order incl. m(R): round R-1's ES->ES hop names its receiver;
-    # dynamic (IoV/LEO) graphs replay seed-deterministically inside
-    ms = scheduler.precompute(config.rounds + 1, dynamic=dyn)
-
     R = config.rounds
+    M = task.num_clusters
     members_of = task.cluster_members
-    parts = [
-        list(members_of[ms[t]]) if full_part
-        else config.sampler.participants(t, members_of[ms[t]])
-        for t in range(R)
-    ]
-    trained = np.array([len(p) > 0 for p in parts])
-
-    params = task.init_params()
-    d = task.num_params()
+    n_max = max(len(m) for m in members_of)
+    full_part = is_full_participation(config.sampler)
+    obs = config.obs
     channel = resolve_channel(config.precision, config.channel,
                               config.qsgd_levels, config.bits_per_param)
+
+    with maybe_span(obs, "schedule"):
+        sched_fn = config.schedule or paper_sqrt_schedule(K, half=False)
+        lrs = np.array([sched_fn(k) for k in range(K)], dtype=np.float32)
+
+        dyn = None
+        if config.dynamic is not None:
+            from repro.core.dynamics import make_dynamic
+
+            dyn = make_dynamic(config.dynamic, M, seed=config.topology_seed)
+            topo = dyn(0)
+        else:
+            topo = make_topology(config.topology, M, seed=config.topology_seed)
+        rng = np.random.default_rng(config.seed)
+        m0 = (
+            int(rng.integers(M))
+            if config.initial_cluster is None
+            else config.initial_cluster
+        )
+        scheduler = _make_scheduler(task, config, topo, m0)
+        # visit order incl. m(R): round R-1's ES->ES hop names its receiver;
+        # dynamic (IoV/LEO) graphs replay seed-deterministically inside
+        ms = scheduler.precompute(R + 1, dynamic=dyn)
+
+        parts = [
+            list(members_of[ms[t]]) if full_part
+            else config.sampler.participants(t, members_of[ms[t]])
+            for t in range(R)
+        ]
+        trained = np.array([len(p) > 0 for p in parts])
+
+        # per-round gamma/mask rows, padded to n_max (zero-weight slots
+        # contribute exact zeros — the padded computation matches the looped
+        # unpadded one)
+        gammas_r = np.zeros((R, n_max), np.float32)
+        mask_r = np.zeros((R, n_max), np.float32)
+        for t in np.flatnonzero(trained):
+            members = members_of[ms[t]]
+            w = task.cluster_weights(ms[t])
+            if full_part:
+                gammas_r[t, : len(members)] = w
+                mask_r[t, : len(members)] = 1.0
+            else:
+                pmask = participation_mask(members, parts[t])
+                w = w * pmask
+                gammas_r[t, : len(members)] = (w / w.sum()).astype(np.float32)
+                mask_r[t, : len(members)] = pmask
+
+        # PRNG subkeys: one fused split chain over the trained rounds
+        # reproduces the looped per-round `split_chain(key, J)` calls
+        # draw-for-draw
+        subs_r = np.zeros((R, interactions, 2), np.uint32)
+        if channel.stochastic:
+            n_tr = int(trained.sum())
+            if n_tr:
+                _, flat = split_chain(jax.random.PRNGKey(config.seed + 1), n_tr * interactions)
+                subs_r[trained] = np.asarray(flat).reshape(n_tr, interactions, 2)
+
+    # every call that runs the model's init has a "model_init" span of its own
+    with maybe_span(obs, "model_init"):
+        params = task.init_params()
+    with maybe_span(obs, "model_init"):
+        d = task.num_params()
     engine = RoundEngine(task.model, channel, local_opt=config.local_opt,
                          client_microbatch=config.client_microbatch,
                          precision=config.precision)
@@ -482,35 +517,7 @@ def _fed_chs_scan_plan(task: FLTask, source, config: FedCHSConfig):
         and config.precision is None
         and (config.local_opt is None or isinstance(config.local_opt, PlainSGD))
     )
-    taps = config.obs is not None and config.obs.taps
-
-    M = task.num_clusters
-    n_max = max(len(m) for m in members_of)
-
-    # per-round gamma/mask rows, padded to n_max (zero-weight slots contribute
-    # exact zeros — the padded computation matches the looped unpadded one)
-    gammas_r = np.zeros((R, n_max), np.float32)
-    mask_r = np.zeros((R, n_max), np.float32)
-    for t in np.flatnonzero(trained):
-        members = members_of[ms[t]]
-        w = task.cluster_weights(ms[t])
-        if full_part:
-            gammas_r[t, : len(members)] = w
-            mask_r[t, : len(members)] = 1.0
-        else:
-            pmask = participation_mask(members, parts[t])
-            w = w * pmask
-            gammas_r[t, : len(members)] = (w / w.sum()).astype(np.float32)
-            mask_r[t, : len(members)] = pmask
-
-    # PRNG subkeys: one fused split chain over the trained rounds reproduces
-    # the looped per-round `split_chain(key, J)` calls draw-for-draw
-    subs_r = np.zeros((R, interactions, 2), np.uint32)
-    if channel.stochastic:
-        n_tr = int(trained.sum())
-        if n_tr:
-            _, flat = split_chain(jax.random.PRNGKey(config.seed + 1), n_tr * interactions)
-            subs_r[trained] = np.asarray(flat).reshape(n_tr, interactions, 2)
+    taps = obs is not None and obs.taps
 
     def _occurrences(idxs):
         """chunk positions grouped by active cluster, in round order."""
@@ -591,7 +598,7 @@ def _fed_chs_scan_plan(task: FLTask, source, config: FedCHSConfig):
 
     plan = ScanPlan(body=body, carry=carry, consts=consts, stage=stage,
                     trained=trained, rounds=R, eval_every=config.eval_every,
-                    chunk_rounds=config.chunk_rounds, obs=config.obs)
+                    chunk_rounds=config.chunk_rounds, obs=obs)
 
     mesh = resolve_mesh(config.mesh)
     if mesh is not None:
@@ -612,7 +619,9 @@ def _fed_chs_scan_plan(task: FLTask, source, config: FedCHSConfig):
     down_bits = DenseChannel(
         downlink_bits_per_param(config.precision, config.bits_per_param)
     ).message_bits(d)
-    up_bits = channel_wire_bits(channel, d, task.param_leaf_sizes())
+    with maybe_span(obs, "model_init"):
+        leaf_sizes = task.param_leaf_sizes()
+    up_bits = channel_wire_bits(channel, d, leaf_sizes)
 
     def traffic(track_events: bool):
         """Closed-form per-round ledger entries from the precomputed
@@ -643,13 +652,14 @@ def _fed_chs_scan_plan(task: FLTask, source, config: FedCHSConfig):
 
 def _run_fed_chs_scanned(task: FLTask, config: FedCHSConfig) -> RunResult:
     obs = config.obs
-    with maybe_span(obs, "precompute"):
-        plan, params_of, traffic = _fed_chs_scan_plan(task, task.source, config)
-    recorder = RunRecorder(task, config.rounds, config.eval_every, obs=obs)
-    carry = run_scan(
-        plan, lambda t, c, losses, _lt: recorder.record(t, params_of(c), losses)
-    )
-    ledger = CommLedger(track_events=config.track_events)
-    with maybe_span(obs, "materialize"):
-        ledger.materialize(traffic(config.track_events))
-    return recorder.result("fed_chs", ledger, params_of(carry))
+    with maybe_span(obs, "call"):
+        with maybe_span(obs, "precompute"):
+            plan, params_of, traffic = _fed_chs_scan_plan(task, task.source, config)
+        recorder = RunRecorder(task, config.rounds, config.eval_every, obs=obs)
+        carry = run_scan(
+            plan, lambda t, c, losses, _lt: recorder.record(t, params_of(c), losses)
+        )
+        ledger = CommLedger(track_events=config.track_events)
+        with maybe_span(obs, "materialize"):
+            ledger.materialize(traffic(config.track_events))
+        return recorder.result("fed_chs", ledger, params_of(carry))

@@ -214,7 +214,8 @@ class RunRecorder:
 
     `obs` (repro.obs.RunTelemetry) is the run's observability carrier: every
     evaluation is wrapped in its "eval" span (the one place eval happens for
-    both looped and scanned paths) and the finished telemetry rides out on
+    both looped and scanned paths), the loss fetch that follows it in a
+    "loss_fetch" span, and the finished telemetry rides out on
     `RunResult.telemetry`.
     """
 
@@ -235,7 +236,8 @@ class RunRecorder:
         self.rounds_log.append(t)
         with maybe_span(self.obs, "eval"):
             self.acc_log.append(self.task.evaluate(params))
-        self.loss_log.append(float("nan") if losses is None else float(jnp.mean(losses)))
+        with maybe_span(self.obs, "loss_fetch"):
+            self.loss_log.append(float("nan") if losses is None else float(jnp.mean(losses)))
 
     def result(self, name: str, ledger: CommLedger, params: PyTree) -> RunResult:
         return RunResult(name, self.rounds_log, self.acc_log, self.loss_log, ledger,

@@ -61,6 +61,10 @@ class RunTelemetry:
                   first read, keeping the scanned driver's async-dispatch
                   pipelining — this is what keeps tapped runs inside the
                   10% overhead gate (benchmarks/run.py --json).
+    counts      — host-side counters the drivers bump at the same
+                  boundaries as their spans (`count`): `staged_bytes`
+                  (bytes of the staged scan inputs handed to the device)
+                  and `trained_rounds`.
     """
 
     taps: bool = True
@@ -70,6 +74,7 @@ class RunTelemetry:
     _rounds: list[int] = field(default_factory=list, repr=False)
     _metrics: dict[str, list[Any]] = field(default_factory=dict, repr=False)
     _pending: list = field(default_factory=list, repr=False)
+    counts: dict[str, int] = field(default_factory=dict)
 
     def __post_init__(self):
         if self.tracer is None:
@@ -77,6 +82,10 @@ class RunTelemetry:
 
     def span(self, name: str):
         return self.tracer.span(name)
+
+    def count(self, name: str, n: int = 1) -> None:
+        """Add `n` to the host counter `name`."""
+        self.counts[name] = self.counts.get(name, 0) + int(n)
 
     # -- tele ingestion ----------------------------------------------------
     @property
@@ -144,4 +153,5 @@ class RunTelemetry:
         return {"rounds": len(self.rounds), "metrics": out,
                 "spans": {name: self.tracer.wall(name)
                           for _, name, _ in self.tracer.events
-                          if name}}
+                          if name},
+                "counts": dict(self.counts)}

@@ -3,9 +3,10 @@
 One run produces three streams of timed facts that previously lived in three
 disconnected places:
 
-  * host spans      — `SpanTracer` B/E pairs over driver phases (precompute,
-                      stage, scan_chunk, round, eval, materialize): REAL
-                      wall-clock of the simulation process;
+  * host spans      — `SpanTracer` B/E pairs over driver phases (call,
+                      precompute > schedule/model_init, stage > draw/
+                      device_put, scan_chunk, round, eval, loss_fetch,
+                      materialize): REAL wall-clock of the simulation process;
   * comm events     — the `CommLedger`'s structured `CommEvent` stream: every
                       metered message of the protocol (no time of its own);
   * netsim timeline — `repro.netsim` job DAG replay: SIMULATED wall-clock of

@@ -219,3 +219,136 @@ def test_phase_bytes_attributes_multi_round_es_hop(small_task):
                             "es_hop": r"es_hop"})
     for phase in ("local_train", "uplink", "intra_agg", "es_hop"):
         assert got.get(phase, 0.0) > 0.0, phase
+
+
+# --------------------------------------------------------------------------
+# a driver call's own work: the "call" span and its children, and the
+# staged_bytes / trained_rounds counters (every scanned driver)
+# --------------------------------------------------------------------------
+
+from repro.core.baselines import (  # noqa: E402
+    FedAvgConfig,
+    HierLocalQSGDConfig,
+    WRWGDConfig,
+    run_fedavg,
+    run_hier_local_qsgd,
+    run_wrwgd,
+)
+
+# each span's parent in a direct call of a scanned driver
+PARENT = {"call": None, "precompute": "call", "schedule": "precompute",
+          "model_init": "precompute", "stage": "call", "draw": "stage",
+          "device_put": "stage", "scan_chunk": "call", "eval": "call",
+          "loss_fetch": "call", "materialize": "call"}
+
+SCANNED = [
+    ("fed_chs", run_fed_chs, FedCHSConfig,
+     dict(rounds=5, local_steps=4, local_epochs=2, eval_every=2, seed=0)),
+    ("fedavg", run_fedavg, FedAvgConfig, dict(rounds=3, local_steps=3, eval_every=2, seed=0)),
+    ("wrwgd", run_wrwgd, WRWGDConfig, dict(rounds=4, local_steps=3, eval_every=2, seed=0)),
+    ("hier_local_qsgd", run_hier_local_qsgd, HierLocalQSGDConfig,
+     dict(rounds=3, local_steps=4, local_epochs=2, eval_every=2, seed=0)),
+]
+
+# Fed-CHS's two scanned bodies: per-step gradients (E=1, dense, no
+# precision policy) and per-interaction client deltas
+FED_CHS_MODES = {
+    "grad": dict(rounds=5, local_steps=3, local_epochs=1, eval_every=2, seed=1),
+    "delta": dict(rounds=5, local_steps=4, local_epochs=2, eval_every=2, seed=2,
+                  qsgd_levels=8),
+}
+
+
+def _span_parents(events) -> list:
+    """(name, parent name) of every span, in the order the spans opened."""
+    out, stack = [], []
+    for kind, name, _ in events:
+        if kind == "B":
+            out.append((name, stack[-1] if stack else None))
+            stack.append(name)
+        else:
+            assert stack.pop() == name
+    assert not stack
+    return out
+
+
+@pytest.mark.parametrize("name,run,cfg_cls,kwargs", SCANNED, ids=[c[0] for c in SCANNED])
+def test_driver_call_spans_nest_under_their_parents(small_task, name, run, cfg_cls, kwargs):
+    obs = RunTelemetry(taps=False)
+    run(small_task, cfg_cls(**kwargs, obs=obs))
+    pairs = _span_parents(obs.tracer.events)
+    assert all(PARENT[n] == p for n, p in pairs), pairs
+    names = [n for n, _ in pairs]
+    assert names[0] == "call" and names.count("call") == 1
+    assert {"precompute", "stage", "draw", "device_put", "scan_chunk", "eval",
+            "loss_fetch", "materialize"} <= set(names)
+    # each stage draws, then hands over; each eval is followed by its loss fetch
+    stages = [i for i, n in enumerate(names) if n == "stage"]
+    assert all(names[i + 1: i + 3] == ["draw", "device_put"] for i in stages)
+    evals = [i for i, n in enumerate(names) if n == "eval"]
+    assert evals and all(names[i + 1] == "loss_fetch" for i in evals)
+    if name == "fed_chs":
+        assert names.count("schedule") == 1 and names.count("model_init") >= 1
+
+
+@pytest.mark.parametrize("mode", sorted(FED_CHS_MODES))
+def test_one_model_init_span_per_init_call(small_task, monkeypatch, mode):
+    real, calls = small_task.init_params, []
+
+    def counting():
+        calls.append(1)
+        return real()
+
+    monkeypatch.setattr(small_task, "init_params", counting)
+    obs = RunTelemetry(taps=False)
+    run_fed_chs(small_task, FedCHSConfig(**FED_CHS_MODES[mode], obs=obs))
+    spans = [n for k, n, _ in obs.tracer.events if k == "B" and n == "model_init"]
+    assert calls and len(spans) == len(calls)
+
+
+@pytest.mark.parametrize("mode", sorted(FED_CHS_MODES))
+def test_staged_bytes_counts_the_plans_own_staged_inputs(small_task, mode):
+    import jax
+
+    from repro.core.fed_chs import _fed_chs_scan_plan
+
+    obs = RunTelemetry(taps=False)
+    run_fed_chs(small_task, FedCHSConfig(**FED_CHS_MODES[mode], obs=obs))
+    # every staged leaf has a leading round axis and fixed trailing dims, so
+    # the chunks' bytes add up to one staging of every trained round
+    plan, _, _ = _fed_chs_scan_plan(small_task, small_task.source,
+                                    FedCHSConfig(**FED_CHS_MODES[mode]))
+    idxs = np.flatnonzero(np.asarray(plan.trained))
+    staged = sum(leaf.nbytes for leaf in jax.tree.leaves(plan.stage(idxs)))
+    assert obs.counts == {"staged_bytes": staged, "trained_rounds": len(idxs)}
+    assert obs.summary()["counts"] == obs.counts
+
+
+@pytest.mark.parametrize("name,run,cfg_cls,kwargs", SCANNED, ids=[c[0] for c in SCANNED])
+def test_obs_none_records_nothing_and_spans_change_no_bit(small_task, monkeypatch,
+                                                          name, run, cfg_cls, kwargs):
+    import jax
+
+    seen = []
+    real_span, real_count = SpanTracer.span, RunTelemetry.count
+
+    def span(self, span_name):
+        seen.append(span_name)
+        return real_span(self, span_name)
+
+    def count(self, counter, n=1):
+        seen.append(counter)
+        real_count(self, counter, n)
+
+    monkeypatch.setattr(SpanTracer, "span", span)
+    monkeypatch.setattr(RunTelemetry, "count", count)
+    base = run(small_task, cfg_cls(**kwargs))
+    assert seen == [] and base.telemetry is None
+    traced = run(small_task, cfg_cls(**kwargs, obs=RunTelemetry(taps=False)))
+    assert {"call", "staged_bytes", "trained_rounds"} <= set(seen)
+    assert base.rounds == traced.rounds
+    np.testing.assert_array_equal(base.train_loss, traced.train_loss)
+    np.testing.assert_array_equal(base.test_acc, traced.test_acc)
+    assert base.ledger.bits == traced.ledger.bits
+    for a, b in zip(jax.tree.leaves(base.final_params), jax.tree.leaves(traced.final_params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
