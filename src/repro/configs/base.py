@@ -89,10 +89,10 @@ class ArchConfig:
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
-    # route training/prefill self-attention through the Pallas flash kernel
-    # (models.attention._flash_attention_ad: fused forward, blockwise-oracle
-    # recompute backward). Off by default — the pure-JAX blockwise path is
-    # the reference everywhere else.
+    # route training/prefill self-attention through the Pallas flash kernels
+    # (models.attention._flash_attention_ad: fused forward, Pallas backward
+    # that recomputes the softmax from the saved logsumexp). Off by default —
+    # the pure-JAX blockwise path and its VJP are the reference everywhere else.
     use_flash: bool = False
     # decode support for the 500k shape (sub-quadratic archs + sliding-window dense)
     long_context_ok: bool = False

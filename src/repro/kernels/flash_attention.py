@@ -1,17 +1,31 @@
-"""Pallas TPU flash-attention kernel (q-blocked causal/windowed GQA).
+"""Pallas TPU flash-attention kernels (q-blocked causal/windowed GQA): the
+forward, and the two backward kernels of its VJP.
 
-Tiling: grid = (B * H, ceil(T / BLOCK_Q)). Each program holds one BLOCK_Q x hd
-query tile in VMEM plus its kv-head's full (S, hd) K and V slabs (VMEM budget
-= 2*S*hd*4 bytes; S<=2048 tiles at hd=128 are ~2 MiB — larger S is handled by
-the pure-JAX online-softmax path in models/attention.py, which this kernel
-mirrors numerically). The MXU sees (BLOCK_Q, hd) @ (hd, S) and
-(BLOCK_Q, S) @ (S, hd) matmuls — both lane-aligned for hd, S multiples of 128.
+Forward: grid = (B * H, ceil(T / BLOCK_Q)). Each program holds one BLOCK_Q x
+hd query tile in VMEM plus its kv-head's full (S, hd) K and V slabs (VMEM
+budget = 2*S*hd*4 bytes; S<=2048 tiles at hd=128 are ~2 MiB). The MXU sees
+(BLOCK_Q, hd) @ (hd, S) and (BLOCK_Q, S) @ (S, hd) matmuls — both
+lane-aligned for hd, S multiples of 128. Run as the forward of the VJP
+(`return_lse=True`) it also writes each row's logsumexp.
 
-GQA: query head h reads kv head h // (H // Hkv) via the K/V BlockSpec index
-maps — no head replication in memory.
+Backward (`flash_attention_bwd`): both kernels recompute
+P = exp(Q K^T * scale - lse) block by block from the saved logsumexp, and
+skip the blocks the causal mask and `window` leave empty.
+  * `flash_bwd_dq`: grid (B * H, T / BLOCK_Q), the kv-head's K and V slabs in
+    VMEM as in the forward; loops over the KV blocks a query block sees.
+  * `flash_bwd_dkv`: grid (B * Hkv, S / BLOCK_K), the Q and dO slabs of the
+    g = H / Hkv query heads that share the KV head in VMEM; loops over the
+    query blocks that see the KV block.
+Matmul operands are in the dtype the kernels are given, products accumulate
+in f32; lse, delta = rowsum(dO * O) and dS are f32.
 
-Used as the TPU fast path for short-S attention (local/sliding-window blocks);
-validated in interpret mode against ref.py / models.attention oracles.
+GQA: query head h reads kv head h // (H // Hkv) via the BlockSpec index maps
+— no head replication in memory.
+
+Used on the training/prefill path of every `use_flash` model
+(`models.attention._flash_attention_ad`); the blockwise online-softmax
+attention in models/attention.py, and its VJP, are the non-flash path and
+the oracle these kernels are validated against (interpret mode off the TPU).
 """
 from __future__ import annotations
 
@@ -23,10 +37,39 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 DEFAULT_BLOCK_Q = 128
+# the backward's query and KV blocks (chip sweep at T=S=2048, hd 128: PERF.md)
+BWD_BLOCK_Q = 512
+BWD_BLOCK_K = 512
 NEG_INF = -1e30
+LANES = 128
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
+def _mask(q_pos, k_pos, *, causal: bool, window: int | None, seq_len: int):
+    mask = k_pos < seq_len                          # padding
+    if causal:
+        mask &= k_pos <= q_pos
+    if window is not None:
+        mask &= k_pos > q_pos - window
+    return mask
+
+
+def _row(col):
+    """(n, 1) -> (1, n), through a lane broadcast and a 2-D transpose."""
+    return jnp.broadcast_to(col, (col.shape[0], LANES)).T[:1]
+
+
+def _col(row):
+    """(1, n) -> (n, 1), the inverse of `_row`."""
+    return jnp.broadcast_to(row, (LANES, row.shape[1])).T[:, :1]
+
+
+def _dot_t(a, b):
+    """a @ b.T with f32 accumulation."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, scale: float, causal: bool,
                   window: int | None, seq_len: int, block_q: int):
     iq = pl.program_id(1)
     q = q_ref[...].astype(jnp.float32) * scale      # (bq, hd)
@@ -35,28 +78,43 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
     s = q @ k.T                                     # (bq, S)
     q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = k_pos < seq_len                          # padding
-    if causal:
-        mask &= k_pos <= q_pos
-    if window is not None:
-        mask &= k_pos > q_pos - window
-    s = jnp.where(mask, s, NEG_INF)
+    s = jnp.where(_mask(q_pos, k_pos, causal=causal, window=window, seq_len=seq_len),
+                  s, NEG_INF)
     m = jnp.max(s, axis=1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=1, keepdims=True)  # noqa: E741 — flash-attn's row-sum name
     o_ref[...] = ((p @ v) / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    if lse_ref:
+        lse_ref[0][...] = _row(m + jnp.log(jnp.maximum(l, 1e-30)))
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _heads_first(x, pad: int):
+    """(B, L, n, hd) -> (B * n, L + pad, hd)."""
+    B, L, n, hd = x.shape
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    return x.transpose(0, 2, 1, 3).reshape(B * n, L + pad, hd)
+
+
+def _heads_last(x, B: int, L: int):
+    """(B * n, Lp, hd) -> (B, L, n, hd)."""
+    n, Lp, hd = x.shape[0] // B, x.shape[1], x.shape[2]
+    return x.reshape(B, n, Lp, hd).transpose(0, 2, 1, 3)[:, :L]
+
+
 @functools.partial(
-    jax.jit, static_argnames=("causal", "window", "block_q")
+    jax.jit, static_argnames=("causal", "window", "block_q", "return_lse")
 )
 def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
-                    block_q: int = DEFAULT_BLOCK_Q):
-    """q (B,T,H,hd); k/v (B,S,Hkv,hd) -> (B,T,H,hd). S padded to 128 inside."""
+                    block_q: int = DEFAULT_BLOCK_Q, return_lse: bool = False):
+    """q (B,T,H,hd); k/v (B,S,Hkv,hd) -> (B,T,H,hd). S padded to 128 inside.
+
+    With `return_lse` also each row's logsumexp of the scaled, masked scores,
+    (B, H, T) f32: what `flash_attention_bwd` recomputes P from."""
     B, T, H, hd = q.shape
     S, Hkv = k.shape[1], k.shape[2]
     assert H % Hkv == 0
@@ -65,20 +123,21 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
 
     pad_t = (-T) % block_q
     pad_s = (-S) % 128
-    qp = jnp.pad(q, ((0, 0), (0, pad_t), (0, 0), (0, 0))) if pad_t else q
-    kp = jnp.pad(k, ((0, 0), (0, pad_s), (0, 0), (0, 0))) if pad_s else k
-    vp = jnp.pad(v, ((0, 0), (0, pad_s), (0, 0), (0, 0))) if pad_s else v
     Tp, Sp = T + pad_t, S + pad_s
-
-    qh = qp.transpose(0, 2, 1, 3).reshape(B * H, Tp, hd)
-    kh = kp.transpose(0, 2, 1, 3).reshape(B * Hkv, Sp, hd)
-    vh = vp.transpose(0, 2, 1, 3).reshape(B * Hkv, Sp, hd)
+    qh, kh, vh = _heads_first(q, pad_t), _heads_first(k, pad_s), _heads_first(v, pad_s)
 
     grid = (B * H, Tp // block_q)
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, window=window,
         seq_len=S, block_q=block_q,
     )
+    o_spec = pl.BlockSpec((None, block_q, hd), lambda bh, iq: (bh, iq, 0))
+    o_shape = jax.ShapeDtypeStruct((B * H, Tp, hd), q.dtype)
+    if return_lse:
+        # 4-D so that the custom call's 3-D shapes stay (out, q, k, v)
+        o_spec = [o_spec, pl.BlockSpec((None, None, 1, block_q),
+                                       lambda bh, iq: (bh, 0, 0, iq))]
+        o_shape = [o_shape, jax.ShapeDtypeStruct((B * H, 1, 1, Tp), jnp.float32)]
 
     out = pl.pallas_call(
         kernel,
@@ -88,10 +147,142 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
             pl.BlockSpec((None, Sp, hd), lambda bh, iq, g=g: (bh // g, 0, 0)),
             pl.BlockSpec((None, Sp, hd), lambda bh, iq, g=g: (bh // g, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((None, block_q, hd), lambda bh, iq: (bh, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Tp, hd), q.dtype),
+        out_specs=o_spec,
+        out_shape=o_shape,
+        name="flash_attention",      # the custom call's name under a VJP too
         interpret=_interpret(),
     )(qh, kh, vh)
 
-    out = out.reshape(B, H, Tp, hd).transpose(0, 2, 1, 3)
-    return out[:, :T]
+    if not return_lse:
+        return _heads_last(out, B, T)
+    out, lse = out
+    return _heads_last(out, B, T), lse.reshape(B, H, Tp)[:, :, :T]
+
+
+# --------------------------------------------------------------------------
+# backward
+# --------------------------------------------------------------------------
+
+
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
+                   scale: float, causal: bool, window: int | None, seq_len: int,
+                   block_q: int, block_k: int):
+    iq = pl.program_id(1)
+    q, do = q_ref[...], do_ref[...]                 # (bq, hd)
+    lse, delta = _col(lse_ref[...]), _col(delta_ref[...])  # (bq, 1)
+    first, last = iq * block_q, iq * block_q + block_q - 1
+    lo, hi = 0, pl.cdiv(seq_len, block_k)
+    if causal:
+        hi = jnp.minimum(hi, last // block_k + 1)
+    if window is not None:
+        lo = jnp.maximum(first - window + 1, 0) // block_k
+    q_pos = first + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+
+    def body(j, acc):
+        start = pl.multiple_of(j * block_k, block_k)
+        k = k_ref[pl.ds(start, block_k), :]
+        v = v_ref[pl.ds(start, block_k), :]
+        s = _dot_t(q, k) * scale                    # (bq, bk)
+        k_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(_mask(q_pos, k_pos, causal=causal, window=window, seq_len=seq_len),
+                      s, NEG_INF)
+        p = jnp.exp(s - lse)
+        ds = p * (_dot_t(do, v) - delta)
+        return acc + jnp.dot(ds.astype(k.dtype), k, preferred_element_type=jnp.float32)
+
+    acc = jax.lax.fori_loop(lo, hi, body, jnp.zeros(q.shape, jnp.float32))
+    dq_ref[...] = (acc * scale).astype(dq_ref.dtype)
+
+
+def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dk_ref, dv_ref, *,
+                    scale: float, causal: bool, window: int | None, seq_len: int,
+                    q_len: int, block_q: int, block_k: int):
+    ik = pl.program_id(1)
+    k, v = k_ref[...], v_ref[...]                   # (bk, hd)
+    first, last = ik * block_k, ik * block_k + block_k - 1
+    lo = first // block_q if causal else 0
+    hi = pl.cdiv(q_len, block_q)                    # rows past T carry no gradient
+    if window is not None:
+        hi = jnp.minimum(hi, (last + window - 1) // block_q + 1)
+    k_pos = first + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
+
+    def body(i, carry):
+        dk, dv = carry
+        start = pl.multiple_of(i * block_q, block_q)
+        q_pos = start + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
+        mask = _mask(q_pos, k_pos, causal=causal, window=window, seq_len=seq_len)
+        for h in range(q_ref.shape[0]):             # the g query heads of this KV head
+            q = q_ref[h, pl.ds(start, block_q), :]  # (bq, hd)
+            do = do_ref[h, pl.ds(start, block_q), :]
+            lse = lse_ref[pl.ds(h, 1), pl.ds(start, block_q)]      # (1, bq)
+            delta = delta_ref[pl.ds(h, 1), pl.ds(start, block_q)]
+            st = jnp.where(mask, _dot_t(k, q) * scale, NEG_INF)    # (bk, bq)
+            pt = jnp.exp(st - lse)
+            dv += jnp.dot(pt.astype(do.dtype), do, preferred_element_type=jnp.float32)
+            dst = pt * (_dot_t(v, do) - delta)
+            dk += jnp.dot(dst.astype(q.dtype), q, preferred_element_type=jnp.float32)
+        return dk, dv
+
+    zero = jnp.zeros(k.shape, jnp.float32)
+    dk, dv = jax.lax.fori_loop(lo, hi, body, (zero, zero))
+    dk_ref[...] = (dk * scale).astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("causal", "window", "block_q", "block_k")
+)
+def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
+                        window: int | None = None, block_q: int = BWD_BLOCK_Q,
+                        block_k: int = BWD_BLOCK_K):
+    """The VJP of `flash_attention`: (dq, dk, dv) from q, k, v, the output o,
+    the rows' logsumexp `lse` (B, H, T) that `flash_attention(...,
+    return_lse=True)` wrote, and the output cotangent do. A block is cut to
+    its sequence rounded up to 128; T is padded to `block_q` and S to
+    `block_k` inside."""
+    B, T, H, hd = q.shape
+    S, Hkv = k.shape[1], k.shape[2]
+    g = H // Hkv
+    scale = 1.0 / math.sqrt(hd)
+    block_q = min(block_q, pl.cdiv(T, 128) * 128)
+    block_k = min(block_k, pl.cdiv(S, 128) * 128)
+    pad_t, pad_s = (-T) % block_q, (-S) % block_k
+    Tp, Sp = T + pad_t, S + pad_s
+
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)  # (B, T, H)
+    rows = lambda x: jnp.pad(x, ((0, 0), (0, 0), (0, pad_t))).reshape(B * H, Tp)  # noqa: E731
+    lse, delta = rows(lse), rows(delta.transpose(0, 2, 1))
+    qh, doh = _heads_first(q, pad_t), _heads_first(do, pad_t)
+    kh, vh = _heads_first(k, pad_s), _heads_first(v, pad_s)
+    opts = dict(scale=scale, causal=causal, window=window, seq_len=S,
+                block_q=block_q, block_k=block_k)
+
+    q_blk = pl.BlockSpec((None, block_q, hd), lambda bh, iq: (bh, iq, 0))
+    kv_slab = pl.BlockSpec((None, Sp, hd), lambda bh, iq, g=g: (bh // g, 0, 0))
+    row_blk = pl.BlockSpec((None, 1, block_q), lambda bh, iq: (bh, 0, iq))
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, **opts),
+        grid=(B * H, Tp // block_q),
+        in_specs=[q_blk, kv_slab, kv_slab, q_blk, row_blk, row_blk],
+        out_specs=q_blk,
+        out_shape=jax.ShapeDtypeStruct((B * H, Tp, hd), q.dtype),
+        name="flash_bwd_dq",
+        interpret=_interpret(),
+    )(qh, kh, vh, doh, lse.reshape(B * H, 1, Tp), delta.reshape(B * H, 1, Tp))
+
+    q_slab = pl.BlockSpec((None, g, Tp, hd), lambda bkv, ik: (bkv, 0, 0, 0))
+    row_slab = pl.BlockSpec((None, g, Tp), lambda bkv, ik: (bkv, 0, 0))
+    kv_blk = pl.BlockSpec((None, block_k, hd), lambda bkv, ik: (bkv, ik, 0))
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, q_len=T, **opts),
+        grid=(B * Hkv, Sp // block_k),
+        in_specs=[q_slab, q_slab, row_slab, row_slab, kv_blk, kv_blk],
+        out_specs=[kv_blk, kv_blk],
+        out_shape=[jax.ShapeDtypeStruct((B * Hkv, Sp, hd), k.dtype),
+                   jax.ShapeDtypeStruct((B * Hkv, Sp, hd), v.dtype)],
+        name="flash_bwd_dkv",
+        interpret=_interpret(),
+    )(qh.reshape(B * Hkv, g, Tp, hd), doh.reshape(B * Hkv, g, Tp, hd),
+      lse.reshape(B * Hkv, g, Tp), delta.reshape(B * Hkv, g, Tp), kh, vh)
+
+    return _heads_last(dq, B, T), _heads_last(dk, B, S), _heads_last(dv, B, S)

@@ -7,11 +7,12 @@ path in repro/kernels/flash_attention.py mirrors. Decode attends one query
 against a fixed-capacity cache (full or ring-buffered sliding window).
 
 With `cfg.use_flash` the training/prefill path routes through the Pallas
-kernel instead (`_flash_attention_ad`): the forward is the fused q-blocked
-kernel, and the backward recomputes attention via this module's blockwise
-oracle and differentiates THAT — the standard flash-attention recompute
-trade (no (T, S) residuals saved; the two implementations agree to kernel
-tolerance, pinned by tests/test_kernels.py).
+kernels instead (`_flash_attention_ad`): the forward is the fused q-blocked
+kernel, which also writes each row's logsumexp when it runs under a VJP, and
+the backward is two Pallas kernels that recompute the softmax from it (no
+(T, S) residuals saved).  `blockwise_attention` and its VJP stay the path
+without flash and the oracle the kernels are tested against
+(tests/test_kernels_flash.py).
 
 Shapes: x (B, T, D); q (B, T, H, hd); kv (B, S, Hkv, hd); caches (B, S, Hkv, hd).
 """
@@ -141,29 +142,25 @@ def blockwise_attention(
 
 @functools.cache
 def _flash_attention_ad(causal: bool, window: int | None):
-    """Differentiable flash attention: Pallas kernel forward, blockwise-oracle
-    backward.  The kernel itself has no VJP rule (it is a fused forward); on
-    the backward pass we recompute the attention with `blockwise_attention`
-    — numerically the same online softmax — and transpose through that.
-    Residuals are just (q, k, v): activation memory stays O(T·hd), never
-    O(T·S), which is the whole point of putting flash on the training path."""
+    """Differentiable flash attention: the Pallas forward kernel, and the
+    Pallas backward kernels of `kernels.flash_attention.flash_attention_bwd`.
+    Run as the VJP's forward, the kernel also writes each row's logsumexp;
+    the backward recomputes P from it block by block.  Residuals are q, k,
+    v, the output and the logsumexp: activation memory stays O(T·hd), never
+    O(T·S), which is the whole point of putting flash on the training path.
+    Called without a VJP (eval, prefill) it is the forward kernel alone."""
+    from repro.kernels.flash_attention import flash_attention, flash_attention_bwd
 
     @jax.custom_vjp
     def fa(q, k, v):
-        from repro.kernels.flash_attention import flash_attention
-
         return flash_attention(q, k, v, causal=causal, window=window)
 
     def fwd(q, k, v):
-        return fa(q, k, v), (q, k, v)
+        o, lse = flash_attention(q, k, v, causal=causal, window=window, return_lse=True)
+        return o, (q, k, v, o, lse)
 
-    def bwd(res, ct):
-        q, k, v = res
-        _, vjp = jax.vjp(
-            lambda q, k, v: blockwise_attention(q, k, v, causal=causal, window=window),
-            q, k, v,
-        )
-        return vjp(ct)
+    def bwd(res, do):
+        return flash_attention_bwd(*res, do, causal=causal, window=window)
 
     fa.defvjp(fwd, bwd)
     return fa

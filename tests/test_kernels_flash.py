@@ -4,8 +4,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.flash_attention import flash_attention
-from repro.models.attention import blockwise_attention
+from repro.kernels.flash_attention import flash_attention, flash_attention_bwd
+from repro.models.attention import _flash_attention_ad, blockwise_attention
 
 
 @pytest.mark.parametrize("T,S", [(128, 128), (64, 256), (200, 200)])
@@ -58,3 +58,102 @@ def test_flash_nonaligned_shapes_padded():
     out = flash_attention(q, k, v, causal=True, block_q=64)
     ref = blockwise_attention(q, k, v, causal=True, kv_block=64)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
+
+
+# --------------------------------------------------------------------------
+# the backward kernels: jax.grad through _flash_attention_ad vs the VJP of
+# the blockwise oracle
+# --------------------------------------------------------------------------
+
+
+def _grads(fn, q, k, v, ct):
+    loss = lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * ct)  # noqa: E731
+    return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("window", [None, 16, 64])
+@pytest.mark.parametrize("T", [200, 50])              # neither a multiple of 128: padded
+@pytest.mark.parametrize("H,Hkv", [(4, 4), (8, 2)])
+def test_flash_backward_matches_blockwise_vjp(H, Hkv, T, window, dtype):
+    key = jax.random.PRNGKey(T + H + (window or 0))
+    B, hd = 1, 32
+    q = jax.random.normal(key, (B, T, H, hd)).astype(dtype)
+    k = jax.random.normal(jax.random.fold_in(key, 1), (B, T, Hkv, hd)).astype(dtype)
+    v = jax.random.normal(jax.random.fold_in(key, 2), (B, T, Hkv, hd)).astype(dtype)
+    ct = jax.random.normal(jax.random.fold_in(key, 3), (B, T, H, hd))
+    got = _grads(_flash_attention_ad(True, window), q, k, v, ct)
+    want = _grads(lambda q, k, v: blockwise_attention(q, k, v, causal=True, window=window),
+                  q, k, v, ct)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert a.dtype == b.dtype == dtype and a.shape == b.shape, name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        err = np.max(np.abs(a - b)) / np.max(np.abs(b))
+        assert err <= tol, (name, err)
+
+
+@pytest.mark.parametrize("window", [None, 40, 100])
+@pytest.mark.parametrize("block_q,block_k", [(32, 64), (64, 32)])
+def test_flash_backward_block_edges(block_q, block_k, window):
+    """Blocks smaller than T and unequal, T padded: the first and last
+    blocks each kernel's loop visits under the causal mask and window."""
+    key = jax.random.PRNGKey(block_q + (window or 0))
+    B, T, H, Hkv, hd = 1, 300, 4, 2, 32
+    q = jax.random.normal(key, (B, T, H, hd))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (B, T, Hkv, hd))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (B, T, Hkv, hd))
+    ct = jax.random.normal(jax.random.fold_in(key, 3), (B, T, H, hd))
+    o, lse = flash_attention(q, k, v, window=window, return_lse=True)
+    got = flash_attention_bwd(q, k, v, o, lse, ct, window=window,
+                              block_q=block_q, block_k=block_k)
+    _, vjp = jax.vjp(lambda q, k, v: blockwise_attention(q, k, v, causal=True, window=window),
+                     q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), got, vjp(ct)):
+        err = np.max(np.abs(np.asarray(a) - np.asarray(b))) / np.max(np.abs(np.asarray(b)))
+        assert err <= 1e-5, (name, err)
+
+
+def _toy_lm(flash: bool):
+    from repro.configs.base import ArchConfig
+    from repro.models.fed import LMFedModel
+
+    cfg = ArchConfig(name="toy-lm", family="dense", num_layers=2, d_model=64, num_heads=4,
+                     num_kv_heads=2, d_ff=128, vocab_size=64, qk_norm=True, dtype="bfloat16",
+                     block_pattern=("attn", "local"), sliding_window=16)
+    return LMFedModel(cfg, remat=True, flash=flash)
+
+
+def _toy_batch():
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (1, 40), 0, 64)
+    return {"tokens": tokens, "labels": jnp.roll(tokens, -1, axis=1)}
+
+
+def test_lm_gradient_with_flash_matches_blockwise():
+    """A 2-layer bf16 LM (one full-causal, one sliding-window block) under
+    remat: every leaf's gradient within bf16 tolerance of the path without
+    flash."""
+    ref, fl = _toy_lm(False), _toy_lm(True)
+    params, batch = ref.init(jax.random.PRNGKey(0)), _toy_batch()
+    want = jax.jit(jax.grad(ref.loss))(params, batch)
+    got = jax.jit(jax.grad(fl.loss))(params, batch)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.linalg.norm(a - b) <= 3e-2 * np.linalg.norm(b)
+
+
+@pytest.mark.parametrize("flash", [True, False], ids=["flash", "blockwise"])
+def test_only_the_path_without_flash_differentiates_the_blockwise_oracle(flash, monkeypatch):
+    """With flash the gradient runs the Pallas kernels and never calls the
+    blockwise oracle; without flash it runs the oracle and no kernel."""
+    from repro.models import attention
+
+    calls = []
+    oracle = attention.blockwise_attention
+    monkeypatch.setattr(attention, "blockwise_attention",
+                        lambda *a, **kw: calls.append(1) or oracle(*a, **kw))
+    model = _toy_lm(flash)
+    params = model.init(jax.random.PRNGKey(0))
+    jaxpr = str(jax.make_jaxpr(jax.grad(model.loss))(params, _toy_batch()))
+    assert bool(calls) is not flash
+    assert ("pallas_call" in jaxpr) is flash

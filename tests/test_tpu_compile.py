@@ -13,6 +13,7 @@ JAX's persistent compilation cache is off around these compiles — an entry
 written for a described chip cannot be read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -97,3 +98,20 @@ def test_flash_attention_compiles(one_chip, on_tpu, seq):
     kv = jax.ShapeDtypeStruct((1, seq, 8, 128), jnp.bfloat16, sharding=one_chip)
     hlo = _compiled(lambda q, k, v: fa.flash_attention(q, k, v), q, kv, kv)
     assert CUSTOM_CALL in hlo
+
+
+@pytest.mark.parametrize("seq", [128, 2048])
+def test_flash_attention_backward_compiles(one_chip, on_tpu, seq):
+    """jax.grad through the flash path at the qwen3 heads: the forward and
+    both backward kernels lower to Mosaic, and no f32 block of 512 scores
+    (the blockwise oracle's scan) is left in the program."""
+    from repro.models.attention import _flash_attention_ad
+
+    q = jax.ShapeDtypeStruct((1, seq, 16, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, seq, 8, 128), jnp.bfloat16, sharding=one_chip)
+    loss = lambda q, k, v: jnp.sum(_flash_attention_ad(True, None)(q, k, v).astype(jnp.float32))  # noqa: E731
+    hlo = _compiled(jax.grad(loss, (0, 1, 2)), q, kv, kv)
+    assert CUSTOM_CALL in hlo
+    for kernel in ("%flash_attention", "%flash_bwd_dq", "%flash_bwd_dkv"):
+        assert kernel in hlo
+    assert not re.search(r"f32\[[\d,]*,512\]", hlo)
