@@ -64,10 +64,16 @@ def find_cell(name: str, root: str = ROOT, bench_dir: str = BENCH_DIR) -> Cell:
     )
 
 
+def metric_module(name: str, bench_dir: str = BENCH_DIR):
+    """The reader file of one per-layer metric: `read(ctx) -> float | None`,
+    and optionally `SPANS`, the host spans it reads besides `cli.HOST_SPANS`."""
+    path = os.path.join(bench_dir, "metrics", name + ".py")
+    return load_module(path, "chipbench_metric_" + name.replace(".", "_"))
+
+
 def metric_reader(name: str, bench_dir: str = BENCH_DIR):
     """`read(ctx) -> float | None` of one per-layer metric."""
-    path = os.path.join(bench_dir, "metrics", name + ".py")
-    return load_module(path, "chipbench_metric_" + name.replace(".", "_")).read
+    return metric_module(name, bench_dir).read
 
 
 def reference(name: str, bench_dir: str = BENCH_DIR):

@@ -28,8 +28,11 @@ import time
 from chipbench import catalog, correct, device, program, traffic
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# the host spans every traced run keeps: the harness's and the driver call's
+# own; a metric reader adds the ones it reads as `SPANS`
 HOST_SPANS = ("precompute", "stage", "scan_chunk", "eval", "materialize",
-              "bench_window", "bench_call")
+              "bench_window", "bench_call", "call", "schedule", "model_init", "draw",
+              "device_put", "loss_fetch")
 
 
 @dataclasses.dataclass
@@ -44,6 +47,7 @@ class Context:
     config: dict
     mix: dict
     peaks: dict            # the peak table's row of the device
+    counts: dict = dataclasses.field(default_factory=dict)  # the window's counter deltas
 
 
 class CompileCounter:
@@ -76,13 +80,20 @@ def failed_rounds(result, eval_every: int) -> int:
                for i, loss in enumerate(result.train_loss) if not math.isfinite(loss))
 
 
-def read_layers(cell, ctx: Context) -> dict:
+def read_layers(cell, ctx: Context, bench_dir: str = catalog.BENCH_DIR) -> dict:
     out = {}
     for m in cell.per_layer:
-        value = catalog.metric_reader(m["name"])(ctx)
+        value = catalog.metric_reader(m["name"], bench_dir)(ctx)
         if value is not None:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
     return out
+
+
+def host_spans(cell, bench_dir: str = catalog.BENCH_DIR) -> tuple:
+    """`HOST_SPANS` and every span a reader of the cell's metrics declares."""
+    declared = (s for m in cell.per_layer
+                for s in getattr(catalog.metric_module(m["name"], bench_dir), "SPANS", ()))
+    return tuple(dict.fromkeys((*HOST_SPANS, *declared)))
 
 
 def logged(result) -> tuple:
@@ -145,6 +156,7 @@ def run(args, t0: float) -> dict:
         opts.python_tracer_level = 0
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
     compiled_before, rounds, failed, calls = compiles.count, 0, 0, []
+    counts_before = dict(prog.config.obs.counts)
     t_w = time.perf_counter()
     with jax.profiler.TraceAnnotation("bench_window"):
         while True:
@@ -160,9 +172,12 @@ def run(args, t0: float) -> dict:
     if trace_dir:
         jax.profiler.stop_trace()
     window_compiles = compiles.count - compiled_before
+    # what the window's calls added to the program's host counters
+    counts = {k: v - counts_before.get(k, 0) for k, v in prog.config.obs.counts.items()}
     peak = device.memory_peak(devs)
     print(f"window: {len(calls)} calls, {rounds} rounds in {window_s:.3f} s, "
           f"{window_compiles} backend compiles", file=sys.stderr, flush=True)
+    print(f"window counters: {json.dumps(counts, sort_keys=True)}", file=sys.stderr, flush=True)
 
     result = {"correct": False, "attempted": rounds, "failed": failed}
     if args.trace:
@@ -170,12 +185,12 @@ def run(args, t0: float) -> dict:
 
         path = next(os.path.join(d, f) for d, _, fs in os.walk(trace_dir)
                     for f in fs if f.endswith(".xplane.pb"))
-        t = tr.load(path, HOST_SPANS)
+        t = tr.load(path, host_spans(cell))
         shutil.rmtree(trace_dir, ignore_errors=True)
         (lo, hi), = t.spans("bench_window")
         used = sorted(t.devices)[: cell.chips]
         ctx = Context(t, used, (lo, hi), window_s, rounds, cell.config, cell.mix,
-                      device.peaks(info["kind"]))
+                      device.peaks(info["kind"]), counts)
         busy = [tr.busy_ps(t.devices[d], lo, hi) for d in used]
         info = dict(info, memory_peak_bytes=peak,
                     busy_s=sum(busy) / len(busy) * 1e-12, window_s=(hi - lo) * 1e-12)

@@ -10,23 +10,114 @@ from __future__ import annotations
 import math
 
 
-def lm_matmul_params(c: dict) -> int:
+def layer_windows(c: dict) -> list:
+    """Each layer's attention window: None for full causal attention, the
+    `sliding_window` for a `sliding_attention` entry of `layer_types`.
+    Without `layer_types` every layer is full; a window stated for no layer
+    (with `use_sliding_window` not false) is an error."""
+    n, types = c["num_hidden_layers"], c.get("layer_types")
+    if types is None:
+        if c.get("sliding_window") is not None and c.get("use_sliding_window", True):
+            raise ValueError("a sliding_window with no layer_types to say which layers use it")
+        return [None] * n
+    if len(types) != n or not set(types) <= {"full_attention", "sliding_attention"}:
+        raise ValueError(f"layer_types must give full_attention or sliding_attention "
+                         f"for each of the {n} layers: {types}")
+    return [c["sliding_window"] if t == "sliding_attention" else None for t in types]
+
+
+def moe_keys(c: dict) -> dict | None:
+    """The expert layers' sizes as the file states them, None for a dense
+    model.  `num_experts` is the number held here, its published value is
+    in the file's `published` object where the cut changed it (`reduced` in
+    `BENCHMARK.json`).  `moe_intermediate_size` defaults to
+    `intermediate_size`; the shared experts' width to
+    `moe_intermediate_size`, and their number to one where only that width
+    is given."""
+    if not c.get("num_experts"):
+        return None
+    f = c.get("moe_intermediate_size") or c["intermediate_size"]
+    shared_f = c.get("shared_expert_intermediate_size")
+    n_shared = c.get("num_shared_experts", 1 if shared_f else 0)
+    return {"num_experts": c["num_experts"], "num_experts_per_tok": c["num_experts_per_tok"],
+            "moe_intermediate_size": f, "num_shared_experts": n_shared,
+            "shared_expert_intermediate_size": (shared_f or f) if n_shared else None,
+            "num_dense_layers": c.get("num_dense_layers", 0),
+            "published_experts": c.get("published", {}).get("num_experts", c["num_experts"])}
+
+
+def ffn_matmul_params(c: dict, layer: int):
+    """Parameters of one layer's FFN that multiply each token: a SwiGLU of
+    `intermediate_size` in a dense layer; in an expert layer the router over
+    every published expert, the top-k routed experts scaled by the share of
+    experts held here (the absent ones' work lies on other chips), and the
+    shared experts.  Capacity padding is not counted."""
+    d, moe = c["hidden_size"], moe_keys(c)
+    if moe is None or layer < moe["num_dense_layers"]:
+        return 3 * d * c["intermediate_size"]
+    routed = (moe["num_experts_per_tok"] * 3 * d * moe["moe_intermediate_size"]
+              * moe["num_experts"] / moe["published_experts"])
+    shared = moe["num_shared_experts"] * 3 * d * (moe["shared_expert_intermediate_size"] or 0)
+    return d * moe["published_experts"] + routed + shared
+
+
+def lm_matmul_params(c: dict):
     """Parameters that take part in a matrix multiply per token: the layers'
-    projections and MLP, and the head (the embedding lookup is a gather)."""
-    d, h, hkv, hd, f = (c["hidden_size"], c["num_attention_heads"],
-                        c["num_key_value_heads"], c["head_dim"], c["intermediate_size"])
-    per_layer = d * h * hd + 2 * d * hkv * hd + h * hd * d + 3 * d * f
-    return c["num_hidden_layers"] * per_layer + d * c["vocab_size"]
+    projections and FFN, and the head (the embedding lookup is a gather)."""
+    d, h, hkv, hd = (c["hidden_size"], c["num_attention_heads"],
+                     c["num_key_value_heads"], c["head_dim"])
+    attn = d * h * hd + 2 * d * hkv * hd + h * hd * d
+    layers = sum(attn + ffn_matmul_params(c, i) for i in range(c["num_hidden_layers"]))
+    return layers + d * c["vocab_size"]
 
 
-def lm_forward_flops_per_token(c: dict, seq: int) -> float:
-    """2 per multiply-add of the matmuls, plus causal attention: scores and
-    values at half the sequence on average (2 * 2 * heads * head_dim * seq / 2)."""
-    attn = 2 * c["num_attention_heads"] * c["head_dim"] * seq
-    return 2 * lm_matmul_params(c) + c["num_hidden_layers"] * attn
+def lm_params(c: dict) -> int:
+    """Every parameter the model holds here: the embedding, an untied head,
+    the final norm, and each layer's two norms, attention (its q and k
+    norms, a qkv bias where `attention_bias`) and FFN: a SwiGLU of
+    `intermediate_size` in a dense layer; the router, the experts held and
+    the shared experts in an expert layer.  The harness holds the program's
+    parameters to it, so `lm_matmul_params` counts the model that runs."""
+    d, h, hkv, hd = (c["hidden_size"], c["num_attention_heads"],
+                     c["num_key_value_heads"], c["head_dim"])
+    attn = d * h * hd + 2 * d * hkv * hd + h * hd * d + 2 * hd
+    if c.get("attention_bias"):
+        attn += (h + 2 * hkv) * hd
+    moe = moe_keys(c)
+
+    def ffn(layer):
+        if moe is None or layer < moe["num_dense_layers"]:
+            return 3 * d * c["intermediate_size"]
+        e = moe["num_experts"]
+        shared = moe["num_shared_experts"] * 3 * d * (moe["shared_expert_intermediate_size"] or 0)
+        return d * e + e * 3 * d * moe["moe_intermediate_size"] + shared
+
+    layers = sum(2 * d + attn + ffn(i) for i in range(c["num_hidden_layers"]))
+    head = 0 if c["tie_word_embeddings"] else d * c["vocab_size"]
+    return c["vocab_size"] * d + head + d + layers
 
 
-def lm_train_flops_per_token(c: dict, seq: int) -> float:
+def keys_per_query(seq: int, window: int | None = None) -> float:
+    """Keys a query attends to on average under the causal mask: T/2, or
+    w - w^2/(2T) for a window w < T (the first w queries see a growing
+    prefix, the rest w keys each)."""
+    if window is None or window >= seq:
+        return seq / 2
+    return window - window * window / (2 * seq)
+
+
+def lm_forward_flops_per_token(c: dict, seq: int):
+    """2 per multiply-add of the matmuls, plus attention: scores and values
+    over the keys each query attends to (2 * 2 * heads * head_dim *
+    `keys_per_query`), full causal layers at half the sequence."""
+    h, hd = c["num_attention_heads"], c["head_dim"]
+    windows = [w for w in layer_windows(c) if w is not None and w < seq]
+    full = c["num_hidden_layers"] - len(windows)
+    attn = full * 2 * h * hd * seq + sum(4 * h * hd * keys_per_query(seq, w) for w in windows)
+    return 2 * lm_matmul_params(c) + attn
+
+
+def lm_train_flops_per_token(c: dict, seq: int):
     """Forward plus backward (twice the forward)."""
     return 3 * lm_forward_flops_per_token(c, seq)
 
@@ -40,9 +131,13 @@ def train_flops_per_round(config: dict, mix: dict) -> float:
     return steps * tokens * lm_train_flops_per_token(config, data["seq"])
 
 
-def flash_forward_flops(batch_heads: int, t: int, s: int, head_dim: int) -> float:
-    """The causal attention forward: 2 * 2 * (B*H) * T * S * hd / 2."""
-    return 2.0 * batch_heads * t * s * head_dim
+def flash_forward_flops(batch_heads: int, t: int, s: int, head_dim: int,
+                        window: int | None = None) -> float:
+    """The causal attention forward: 2 * 2 * (B*H) * T * S * hd / 2, or with
+    a window w < S, 2 * 2 * (B*H) * T * hd * (w - w^2 / (2S))."""
+    if window is None or window >= s:
+        return 2.0 * batch_heads * t * s * head_dim
+    return 4.0 * batch_heads * t * head_dim * keys_per_query(s, window)
 
 
 def packed_wire_bytes(n: int, bits: int, block: int) -> int:

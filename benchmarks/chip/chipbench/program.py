@@ -11,13 +11,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import traffic
+from chipbench import counts, traffic
 
 
 # --------------------------------------------------------------------------
@@ -30,14 +31,30 @@ def _leaf_name(path) -> str:
     return str(getattr(last, "key", getattr(last, "name", last)))
 
 
-def _init_leaf(name: str, key, shape, dtype):
-    """The benchmark's init rule: norm weights one, biases zero, the
-    embedding N(0, 0.02^2), every other matrix N(0, 1 / fan_in)."""
+def _init_rule(path, shape) -> str:
+    """The benchmark's init rule of one leaf: norm weights one, biases zero,
+    the embedding N(0, 0.02^2), every other matrix N(0, 1 / fan_in).  The
+    scanned layers (`super`) are stacked on a leading axis.  A leaf that is
+    no matrix and has no rule is an error that names it."""
+    name = _leaf_name(path)
+    rank = len(shape) - int(any(getattr(k, "key", None) == "super" for k in path))
     if name in ("ln1", "ln2", "final_norm", "q_norm", "k_norm"):
-        return jnp.ones(shape, dtype)
-    if name.startswith("b") and len(shape) == 1:
-        return jnp.zeros(shape, dtype)
+        return "ones"
+    if name.startswith("b") and rank == 1:
+        return "zeros"
     if name == "embed":
+        return "normal_0.02"
+    if rank >= 2:
+        return "fan_in"
+    raise ValueError(f"no init rule for the leaf {name!r} of shape {tuple(shape)}")
+
+
+def _init_leaf(rule: str, key, shape, dtype):
+    if rule == "ones":
+        return jnp.ones(shape, dtype)
+    if rule == "zeros":
+        return jnp.zeros(shape, dtype)
+    if rule == "normal_0.02":
         return (0.02 * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
     w = jax.random.normal(key, shape, jnp.float32) * np.sqrt(1.0 / shape[-2])
     return w.astype(dtype)
@@ -47,8 +64,8 @@ def _init_leaf(name: str, key, shape, dtype):
 def _weights_fn(treedef, leaves: tuple):
     def make(key):
         out = []
-        for i, (name, shape, dtype) in enumerate(leaves):
-            out.append(_init_leaf(name, jax.random.fold_in(key, i), shape, dtype))
+        for i, (rule, shape, dtype) in enumerate(leaves):
+            out.append(_init_leaf(rule, jax.random.fold_in(key, i), shape, dtype))
         return jax.tree.unflatten(treedef, out)
 
     return jax.jit(make)
@@ -57,7 +74,7 @@ def _weights_fn(treedef, leaves: tuple):
 def weights_maker(like, seed: int):
     """() -> a fresh weights pytree shaped like `like` (ShapeDtypeStructs)."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(like)
-    leaves = tuple((_leaf_name(p), tuple(x.shape), jnp.dtype(x.dtype)) for p, x in flat)
+    leaves = tuple((_init_rule(p, x.shape), tuple(x.shape), jnp.dtype(x.dtype)) for p, x in flat)
     fn = _weights_fn(treedef, leaves)
     key = jax.random.PRNGKey(traffic.sub_seed(seed, traffic.TAG_WEIGHTS))
     return lambda: fn(key)
@@ -113,22 +130,76 @@ class SeededModel:
         return self.inner.eval_metric(params, eval_data)
 
 
+# `ArchConfig` fields that a file's standard keys set, and fields of layers
+# that `chipbench.counts` cannot count: a file's `arch` object sets neither
+SET_BY_KEYS = ("name", "family", "num_layers", "d_model", "num_heads", "num_kv_heads",
+               "head_dim", "d_ff", "vocab_size", "qkv_bias", "qk_norm", "rope_theta", "act",
+               "norm_eps", "tie_embeddings", "dtype", "use_flash", "sliding_window",
+               "block_pattern", "num_experts", "experts_per_token", "num_shared_experts")
+UNCOUNTED = ("mla", "ssm_state", "ssm_conv", "ssm_expand", "ssm_head_dim", "ssm_chunk",
+             "lru_width", "encoder_layers", "num_audio_frames", "num_patches", "mtp_depth")
+
+
+def block_pattern(windows: list) -> tuple:
+    """The layers' kinds (`local` where a layer has a window, else `attn`)
+    as their shortest period, so the program scans one group a period."""
+    kinds = ["attn" if w is None else "local" for w in windows]
+    n = len(kinds)
+    period = next(p for p in range(1, n + 1) if all(kinds[i] == kinds[i % p] for i in range(n)))
+    return tuple(kinds[:period])
+
+
 def arch_config(config: dict):
-    """The program's `ArchConfig` for a decoder LM configuration file."""
+    """The program's `ArchConfig` for a decoder LM configuration file: the
+    standard keys, the windows and experts from the published-style keys
+    that `chipbench.counts` reads, then the file's `arch` object of the
+    other `ArchConfig` fields (a list becomes a tuple)."""
     from repro.configs.base import ArchConfig
 
-    assert config.get("hidden_act", "silu") == "silu"
-    return ArchConfig(
-        name=config["name"], family="dense",
+    if config.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"hidden_act {config['hidden_act']!r}: the program's LM gates with silu")
+    windows, moe = counts.layer_windows(config), counts.moe_keys(config)
+    arch = ArchConfig(
+        name=config["name"], family="moe" if moe else "dense",
         num_layers=config["num_hidden_layers"], d_model=config["hidden_size"],
         num_heads=config["num_attention_heads"],
         num_kv_heads=config["num_key_value_heads"], head_dim=config["head_dim"],
-        d_ff=config["intermediate_size"], vocab_size=config["vocab_size"],
+        d_ff=moe["moe_intermediate_size"] if moe else config["intermediate_size"],
+        vocab_size=config["vocab_size"],
         qkv_bias=bool(config.get("attention_bias", False)),
-        qk_norm=True, rope_theta=float(config["rope_theta"]), act="silu",
+        qk_norm=True, rope_theta=float(config["rope_theta"]),
+        sliding_window=next((w for w in windows if w is not None), None),
+        block_pattern=block_pattern(windows), act="silu",
+        num_experts=moe["num_experts"] if moe else 0,
+        experts_per_token=moe["num_experts_per_tok"] if moe else 0,
+        num_shared_experts=moe["num_shared_experts"] if moe else 0,
         norm_eps=float(config["rms_norm_eps"]),
         tie_embeddings=bool(config["tie_word_embeddings"]),
         dtype=config["precision"]["master"])
+    over = config.get("arch") or {}
+    unknown = sorted(set(over) - {f.name for f in dataclasses.fields(ArchConfig)})
+    if unknown:
+        raise ValueError(f"{config['name']}: 'arch' names no ArchConfig field {unknown}")
+    refused = sorted(set(over) & set(SET_BY_KEYS + UNCOUNTED))
+    if refused:
+        raise ValueError(f"{config['name']}: 'arch' may not set {refused}: the file's standard "
+                         f"keys set them, or chipbench.counts cannot count their layers")
+    fields = {k: tuple(v) if isinstance(v, list) else v for k, v in over.items()}
+    return dataclasses.replace(arch, **fields) if fields else arch
+
+
+def check_counts_agree(config: dict, like) -> None:
+    """The model `chipbench.counts` counts from the file's keys is the model
+    the program built: the parameters the counts hold (`counts.lm_params`)
+    are the sizes of the program's leaves (`like`, from `jax.eval_shape` of
+    its init), summed.  A leading dense layer, an expert width or a number
+    of experts that the program does not build is an error, not a quiet
+    miscount of `train_mfu`."""
+    built = sum(math.prod(x.shape) for x in jax.tree.leaves(like))
+    stated = counts.lm_params(config)
+    if built != stated:
+        raise ValueError(f"{config['name']}: the program builds {built:,} parameters, "
+                         f"the file's keys count {stated:,}")
 
 
 def inner_model(config: dict):
@@ -171,6 +242,7 @@ def build(config: dict, fed: traffic.Federation, seed: int, tracer) -> Program:
 
     inner = inner_model(config)
     like = jax.eval_shape(inner.init, jax.random.PRNGKey(0))
+    check_counts_agree(config, like)
     make = weights_maker(like, seed)
     model = SeededModel(inner, make)
     task = FLTask.from_source(model, fed.source, fed.clusters, seed=0)

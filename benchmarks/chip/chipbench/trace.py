@@ -21,6 +21,8 @@ from chipbench.xspace import read_xspace, stat_value
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 # ops whose event spans the ops of their body: left out, the body is counted
 CONTAINERS = ("while", "conditional", "call")
+# a windowed kernel's instruction name: `<kernel>_w<window>`, with XLA's clone suffix
+WINDOWED = re.compile(r"_w(\d+)(?:\.\d+)?$")
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute", "collective-broadcast")
 
@@ -152,6 +154,13 @@ def kernel_events(ops: Ops, prefix: str) -> list:
     return [(int(e - s), t) for s, e, n, t, c in
             zip(ops.start, ops.end, ops.name, ops.text, ops.category)
             if n.startswith(prefix) and c == "custom-call"]
+
+
+def kernel_window(text: str) -> int | None:
+    """The attention window a kernel's name states (`flash_attention_w2048.3`
+    -> 2048); None for a kernel named without one."""
+    m = WINDOWED.search(text.split(" ", 1)[0])
+    return int(m.group(1)) if m else None
 
 
 def during(ops: Ops, spans: list, exclude_module: str = "") -> int:

@@ -25,12 +25,22 @@ LM_TOY = dict(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
 TOY_LIMITS = {"loss_gap": 2e-3, "update_gap": 0.035, "change_gap": 0.15, "metric_gap": 0.012}
 
 
-def toy(name: str):
+def cut(config: dict) -> dict:
+    """A configuration at toy size: `LM_TOY`, then the file's own `toy`
+    object (its experts, window and period of layers at toy size); an
+    object in it, such as `arch`, updates the file's object of that key."""
+    out = dict(config, **LM_TOY)
+    for key, value in config.get("toy", {}).items():
+        out[key] = dict(out.get(key) or {}, **value) if isinstance(value, dict) else value
+    return out
+
+
+def toy(name: str, root: str = catalog.ROOT, bench_dir: str = catalog.BENCH_DIR):
     """The cell `name` of `BENCHMARK.json` at toy size: the same mix kind and
     schedule, with the toy limits."""
-    cell = copy.deepcopy(catalog.find_cell(name))
+    cell = copy.deepcopy(catalog.find_cell(name, root=root, bench_dir=bench_dir))
     cell.limits = dict(TOY_LIMITS)
-    cell.config.update(LM_TOY)
+    cell.config = cut(cell.config)
     cell.mix["data"].update(seq=32)
     cell.mix["federation"].update(eval_every=2)
     return cell
