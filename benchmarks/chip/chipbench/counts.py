@@ -7,7 +7,10 @@ Recomputed (rematerialised) operations are not counted.
 """
 from __future__ import annotations
 
+import json
 import math
+import os
+import re
 
 
 def layer_windows(c: dict) -> list:
@@ -61,38 +64,83 @@ def ffn_matmul_params(c: dict, layer: int):
     return d * moe["published_experts"] + routed + shared
 
 
-def lm_matmul_params(c: dict):
-    """Parameters that take part in a matrix multiply per token: the layers'
-    projections and FFN, and the head (the embedding lookup is a gather)."""
+# a block's traits that the counts read; a block file's `counted` object
+# states those its `config.json` does not, and an absent one is off
+COUNTED = ("qk_norm", "attn_out_gate", "post_norms")
+BLOCKS_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "blocks")
+
+
+def block_file(c: dict) -> dict:
+    """`blocks/<model_type>.json`: what a block of the file's published
+    `model_type` holds that `config.json` does not state.  Its `counted`
+    object gives the traits of COUNTED (AFMoE: an output gate
+    o = W_o (sigmoid(h W_g) * attn(h)) with W_g of d x H*hd, and RMSNorms on
+    the attention and FFN outputs, four a layer), its `program` object the
+    `ArchConfig` fields that only the program reads.  A model_type with no
+    file, or a counted trait the counts do not know, is an error."""
+    kind = c.get("model_type")
+    path = os.path.join(BLOCKS_DIR, f"{kind}.json")
+    if not isinstance(kind, str) or not re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.-]*", kind) \
+            or not os.path.isfile(path):
+        known = sorted(n[:-5] for n in os.listdir(BLOCKS_DIR) if n.endswith(".json"))
+        raise ValueError(f"model_type {kind!r}: there are block files for {known}")
+    with open(path) as f:
+        body = json.load(f)
+    unknown = sorted(set(body["counted"]) - set(COUNTED))
+    if unknown:
+        raise ValueError(f"blocks/{kind}.json: the counts know no trait {unknown}")
+    return body
+
+
+def block(c: dict) -> dict:
+    """The counted traits of the file's block, each True or False."""
+    counted = block_file(c)["counted"]
+    return {t: bool(counted.get(t, False)) for t in COUNTED}
+
+
+def attn_matmul_params(c: dict) -> int:
+    """The attention's projections: q, k, v, o and an output gate d x H*hd."""
     d, h, hkv, hd = (c["hidden_size"], c["num_attention_heads"],
                      c["num_key_value_heads"], c["head_dim"])
-    attn = d * h * hd + 2 * d * hkv * hd + h * hd * d
+    gate = d * h * hd if block(c)["attn_out_gate"] else 0
+    return d * h * hd + 2 * d * hkv * hd + h * hd * d + gate
+
+
+def lm_matmul_params(c: dict):
+    """Parameters that take part in a matrix multiply per token: the layers'
+    projections and FFN, and the head (the embedding lookup is a gather).
+    Element-wise work (norms, the gate's sigmoid and product) is not counted."""
+    attn = attn_matmul_params(c)
     layers = sum(attn + ffn_matmul_params(c, i) for i in range(c["num_hidden_layers"]))
-    return layers + d * c["vocab_size"]
+    return layers + c["hidden_size"] * c["vocab_size"]
 
 
 def lm_params(c: dict) -> int:
     """Every parameter the model holds here: the embedding, an untied head,
-    the final norm, and each layer's two norms, attention (its q and k
-    norms, a qkv bias where `attention_bias`) and FFN: a SwiGLU of
-    `intermediate_size` in a dense layer; the router, the experts held and
-    the shared experts in an expert layer.  The harness holds the program's
-    parameters to it, so `lm_matmul_params` counts the model that runs."""
+    the final norm, and each layer's norms (two, four with `post_norms`),
+    attention (its q and k norms with `qk_norm`, a qkv bias where
+    `attention_bias`, an output gate) and FFN: a SwiGLU of
+    `intermediate_size` in a dense layer; in an expert layer the router over
+    the published experts, the experts held and the shared experts.  The
+    harness holds the program's parameters to it, so `lm_matmul_params`
+    counts the model that runs."""
     d, h, hkv, hd = (c["hidden_size"], c["num_attention_heads"],
                      c["num_key_value_heads"], c["head_dim"])
-    attn = d * h * hd + 2 * d * hkv * hd + h * hd * d + 2 * hd
+    traits = block(c)
+    attn = attn_matmul_params(c) + (2 * hd if traits["qk_norm"] else 0)
     if c.get("attention_bias"):
         attn += (h + 2 * hkv) * hd
+    norms = (4 if traits["post_norms"] else 2) * d
     moe = moe_keys(c)
 
     def ffn(layer):
         if moe is None or layer < moe["num_dense_layers"]:
             return 3 * d * c["intermediate_size"]
-        e = moe["num_experts"]
         shared = moe["num_shared_experts"] * 3 * d * (moe["shared_expert_intermediate_size"] or 0)
-        return d * e + e * 3 * d * moe["moe_intermediate_size"] + shared
+        return (d * moe["published_experts"]
+                + moe["num_experts"] * 3 * d * moe["moe_intermediate_size"] + shared)
 
-    layers = sum(2 * d + attn + ffn(i) for i in range(c["num_hidden_layers"]))
+    layers = sum(norms + attn + ffn(i) for i in range(c["num_hidden_layers"]))
     head = 0 if c["tie_word_embeddings"] else d * c["vocab_size"]
     return c["vocab_size"] * d + head + d + layers
 

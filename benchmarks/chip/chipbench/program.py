@@ -32,13 +32,17 @@ def _leaf_name(path) -> str:
 
 
 def _init_rule(path, shape) -> str:
-    """The benchmark's init rule of one leaf: norm weights one, biases zero,
-    the embedding N(0, 0.02^2), every other matrix N(0, 1 / fan_in).  The
-    scanned layers (`super`) are stacked on a leading axis.  A leaf that is
-    no matrix and has no rule is an error that names it."""
+    """The benchmark's init rule of one leaf: norm weights (vectors named
+    `ln*` or `*norm`) one, biases zero, the embedding N(0, 0.02^2), every
+    other matrix N(0, 1 / fan_in).  The scanned layers (`super`) are stacked
+    on a leading axis.  A leaf that is no matrix and has no rule, or a norm
+    that is no vector, is an error that names it."""
     name = _leaf_name(path)
     rank = len(shape) - int(any(getattr(k, "key", None) == "super" for k in path))
-    if name in ("ln1", "ln2", "final_norm", "q_norm", "k_norm"):
+    if name.startswith("ln") or name.endswith("norm"):
+        if rank != 1:
+            raise ValueError(f"the norm {name!r} of shape {tuple(shape)} is no vector: "
+                             f"stacked layers go under 'super'")
         return "ones"
     if name.startswith("b") and rank == 1:
         return "zeros"
@@ -130,14 +134,17 @@ class SeededModel:
         return self.inner.eval_metric(params, eval_data)
 
 
-# `ArchConfig` fields that a file's standard keys set, and fields of layers
-# that `chipbench.counts` cannot count: a file's `arch` object sets neither
+# `ArchConfig` fields that a file's standard keys set (`qk_norm` from its
+# block file), and fields of layers that `chipbench.counts` cannot count: a
+# file's `arch` object sets neither, nor a field that `keyed_fields` sets
 SET_BY_KEYS = ("name", "family", "num_layers", "d_model", "num_heads", "num_kv_heads",
                "head_dim", "d_ff", "vocab_size", "qkv_bias", "qk_norm", "rope_theta", "act",
                "norm_eps", "tie_embeddings", "dtype", "use_flash", "sliding_window",
                "block_pattern", "num_experts", "experts_per_token", "num_shared_experts")
 UNCOUNTED = ("mla", "ssm_state", "ssm_conv", "ssm_expand", "ssm_head_dim", "ssm_chunk",
              "lru_width", "encoder_layers", "num_audio_frames", "num_patches", "mtp_depth")
+# routing keys whose only value the program and the counts know is 1
+GROUP_KEYS = ("n_group", "topk_group", "num_expert_groups", "num_limited_groups")
 
 
 def block_pattern(windows: list) -> tuple:
@@ -149,25 +156,62 @@ def block_pattern(windows: list) -> tuple:
     return tuple(kinds[:period])
 
 
+def keyed_fields(config: dict) -> list:
+    """(key, `ArchConfig` field, value) for each published key beyond the
+    standard ones that is on, each counted trait of the file's block that is
+    on, and each field its block file gives the program: a key that is
+    absent or at its off value sets nothing."""
+    moe = counts.moe_keys(config) or {}
+    dense = moe.get("num_dense_layers", 0)
+    router = moe.get("published_experts")
+    scale = config.get("route_scale")
+    on = [
+        ("num_dense_layers", "num_dense_layers", dense or None),
+        ("intermediate_size", "dense_d_ff", config["intermediate_size"] if dense else None),
+        ("published.num_experts", "router_experts",
+         router if router != moe.get("num_experts") else None),
+        ("score_func", "router_score", config.get("score_func")),
+        ("route_norm", "route_norm", True if config.get("route_norm") else None),
+        ("route_scale", "route_scale", None if scale in (None, 1) else float(scale)),
+        ("mup_enabled", "embed_scale",
+         math.sqrt(config["hidden_size"]) if config.get("mup_enabled") else None),
+    ]
+    counted = [t for t, v in counts.block(config).items() if v and t not in SET_BY_KEYS]
+    traits = {**dict.fromkeys(counted, True), **counts.block_file(config)["program"]}
+    on += [(f"model_type {config['model_type']}", f, v) for f, v in traits.items()]
+    return [(key, f, v) for key, f, v in on if v is not None]
+
+
 def arch_config(config: dict):
     """The program's `ArchConfig` for a decoder LM configuration file: the
     standard keys, the windows and experts from the published-style keys
-    that `chipbench.counts` reads, then the file's `arch` object of the
-    other `ArchConfig` fields (a list becomes a tuple)."""
+    that `chipbench.counts` reads, the routing and embedding keys and the
+    block's traits of `keyed_fields`, then the file's `arch` object of the
+    other `ArchConfig` fields (a list becomes a tuple).  An expert layer
+    holds the first `num_experts` of the `router_experts` its router
+    scores.  A key that is on, whose field `ArchConfig` lacks, is an error
+    that names the key and the field."""
     from repro.configs.base import ArchConfig
 
+    name = config["name"]
     if config.get("hidden_act", "silu") != "silu":
         raise ValueError(f"hidden_act {config['hidden_act']!r}: the program's LM gates with silu")
+    grouped = {k: config[k] for k in GROUP_KEYS if config.get(k, 1) != 1}
+    if grouped:
+        raise ValueError(f"{name}: group-limited routing {grouped}: the counts know one group")
+    if config.get("score_func", "softmax") not in ("sigmoid", "softmax"):
+        raise ValueError(f"{name}: score_func {config['score_func']!r} is neither sigmoid "
+                         f"nor softmax")
     windows, moe = counts.layer_windows(config), counts.moe_keys(config)
     arch = ArchConfig(
-        name=config["name"], family="moe" if moe else "dense",
+        name=name, family="moe" if moe else "dense",
         num_layers=config["num_hidden_layers"], d_model=config["hidden_size"],
         num_heads=config["num_attention_heads"],
         num_kv_heads=config["num_key_value_heads"], head_dim=config["head_dim"],
         d_ff=moe["moe_intermediate_size"] if moe else config["intermediate_size"],
         vocab_size=config["vocab_size"],
         qkv_bias=bool(config.get("attention_bias", False)),
-        qk_norm=True, rope_theta=float(config["rope_theta"]),
+        qk_norm=counts.block(config)["qk_norm"], rope_theta=float(config["rope_theta"]),
         sliding_window=next((w for w in windows if w is not None), None),
         block_pattern=block_pattern(windows), act="silu",
         num_experts=moe["num_experts"] if moe else 0,
@@ -176,16 +220,23 @@ def arch_config(config: dict):
         norm_eps=float(config["rms_norm_eps"]),
         tie_embeddings=bool(config["tie_word_embeddings"]),
         dtype=config["precision"]["master"])
+    fields = {f.name for f in dataclasses.fields(ArchConfig)}
+    keyed = keyed_fields(config)
+    lacking = [f"{key} -> {f}" for key, f, _ in keyed if f not in fields]
+    if lacking:
+        raise ValueError(f"{name}: the program's ArchConfig has no field for "
+                         f"{', '.join(lacking)}")
+    arch = dataclasses.replace(arch, **{f: v for _, f, v in keyed})
     over = config.get("arch") or {}
-    unknown = sorted(set(over) - {f.name for f in dataclasses.fields(ArchConfig)})
+    unknown = sorted(set(over) - fields)
     if unknown:
-        raise ValueError(f"{config['name']}: 'arch' names no ArchConfig field {unknown}")
-    refused = sorted(set(over) & set(SET_BY_KEYS + UNCOUNTED))
+        raise ValueError(f"{name}: 'arch' names no ArchConfig field {unknown}")
+    refused = sorted(set(over) & (set(SET_BY_KEYS + UNCOUNTED) | {f for _, f, _ in keyed}))
     if refused:
-        raise ValueError(f"{config['name']}: 'arch' may not set {refused}: the file's standard "
-                         f"keys set them, or chipbench.counts cannot count their layers")
-    fields = {k: tuple(v) if isinstance(v, list) else v for k, v in over.items()}
-    return dataclasses.replace(arch, **fields) if fields else arch
+        raise ValueError(f"{name}: 'arch' may not set {refused}: the file's keys or its "
+                         f"block file set them, or chipbench.counts cannot count their layers")
+    return dataclasses.replace(arch, **{k: tuple(v) if isinstance(v, list) else v
+                                        for k, v in over.items()})
 
 
 def check_counts_agree(config: dict, like) -> None:

@@ -1,8 +1,11 @@
 """The work counted for windowed and expert layers, from a configuration's
 published-style keys, against hand counts: keys per query under a window,
-the expert layers' FFN scaled by the share of experts held, and the flash
-readers on kernels named with their window."""
+the expert layers' FFN scaled by the share of experts held, the AFMoE
+block's output gate and post-norms, and the flash readers on kernels named
+with their window."""
+import json
 import os
+import shutil
 import sys
 
 import pytest
@@ -15,17 +18,28 @@ from chipbench import trace as tr  # noqa: E402
 from test_chipbench_flash_bwd import BF16, PEAKS, _ctx  # noqa: E402
 
 S, F = "sliding_attention", "full_attention"
-# Trinity-Mini's widths (huggingface.co/arcee-ai/Trinity-Mini config.json),
-# cut to its two dense layers and one period and a half: 8 of its 128 experts held
+# Trinity-Mini (huggingface.co/arcee-ai/Trinity-Mini config.json, model_type
+# afmoe), cut to its two dense layers and one period and a half: 8 of its
+# 128 experts held
 TRINITY_CUT = {
+    "model_type": "afmoe", "hidden_act": "silu",
     "hidden_size": 2048, "num_attention_heads": 32, "num_key_value_heads": 4, "head_dim": 128,
     "intermediate_size": 6144, "vocab_size": 200192, "tie_word_embeddings": False,
     "num_hidden_layers": 6, "layer_types": [S, S, S, F, S, S], "sliding_window": 2048,
-    "num_dense_layers": 2, "num_experts": 8, "num_experts_per_tok": 8,
-    "moe_intermediate_size": 1024, "num_shared_experts": 1,
+    "global_attn_every_n_layers": 4, "num_dense_layers": 2, "num_experts": 8,
+    "num_experts_per_tok": 8, "moe_intermediate_size": 1024, "num_shared_experts": 1,
+    "score_func": "sigmoid", "route_norm": True, "route_scale": 2.826,
+    "load_balance_coeff": 0.001, "mup_enabled": True, "n_group": 1, "topk_group": 1,
+    "num_expert_groups": 1, "num_limited_groups": 1, "rms_norm_eps": 1e-05,
+    "rope_theta": 10000, "rope_scaling": None, "max_position_embeddings": 131072,
+    "use_grouped_mm": True,
     "published": {"num_hidden_layers": 32, "num_experts": 128,
                   "layer_types": [S, S, S, F] * 8},
 }
+# the planned cell's cut (model-configs guide, section 4): 16 of the 128
+# experts, as one of 8 chips that share each layer, and an eighth of the vocabulary
+TRINITY_MINI = dict(TRINITY_CUT, num_experts=16, vocab_size=25024,
+                    published=dict(TRINITY_CUT["published"], vocab_size=200192))
 
 
 def test_keys_per_query_under_a_window():
@@ -63,7 +77,8 @@ def test_layer_windows():
 def test_expert_layers_by_hand():
     d = 2048
     attn = d * 32 * 128 + 2 * d * 4 * 128 + 32 * 128 * d
-    assert attn == 18_874_368
+    gate = d * 32 * 128                                          # AFMoE's output gate
+    assert (attn, gate) == (18_874_368, 8_388_608)
     dense = 3 * d * 6144
     assert counts.ffn_matmul_params(TRINITY_CUT, 0) == dense
     assert counts.ffn_matmul_params(TRINITY_CUT, 1) == dense
@@ -71,29 +86,87 @@ def test_expert_layers_by_hand():
     router, routed, shared = d * 128, 8 * 3 * d * 1024 * 8 / 128, 3 * d * 1024
     assert (router, routed, shared) == (262_144, 3_145_728, 6_291_456)
     assert counts.ffn_matmul_params(TRINITY_CUT, 2) == router + routed + shared == 9_699_328
-    matmul = 6 * attn + 2 * dense + 4 * (router + routed + shared) + d * 200192
-    assert counts.lm_matmul_params(TRINITY_CUT) == matmul == 637_534_208
+    matmul = 6 * (attn + gate) + 2 * dense + 4 * (router + routed + shared) + d * 200192
+    assert counts.lm_matmul_params(TRINITY_CUT) == matmul == 687_865_856
     # five windowed layers at 1,792 keys a query, one full at 4,096
     attn_flops = 1 * 2 * 32 * 128 * 8192 + 5 * 4 * 32 * 128 * 1792
     assert attn_flops == 213_909_504
     assert counts.lm_forward_flops_per_token(TRINITY_CUT, 8192) == 2 * matmul + attn_flops
-    assert counts.lm_train_flops_per_token(TRINITY_CUT, 8192) == 3 * 1_488_977_920
+    assert counts.lm_train_flops_per_token(TRINITY_CUT, 8192) == 3 * 1_589_641_216
 
 
 def test_parameters_held_by_hand():
     d = 2048
     attn = d * 32 * 128 + 2 * d * 4 * 128 + 32 * 128 * d + 2 * 128     # q and k norms
+    gate, norms = d * 32 * 128, 4 * d             # the output gate; norms before and after
     dense = 3 * d * 6144
-    # the router over the 8 experts held, the 8 experts, one shared expert
-    expert_layer = d * 8 + 8 * 3 * d * 1024 + 3 * d * 1024
-    assert (attn, dense, expert_layer) == (18_874_624, 37_748_736, 56_639_488)
-    layers = 6 * (2 * d + attn) + 2 * dense + 4 * expert_layer
+    # the router over the 128 published experts (the guide's section 4: it
+    # keeps its published width), the 8 experts held, one shared expert
+    expert_layer = d * 128 + 8 * 3 * d * 1024 + 3 * d * 1024
+    assert (attn, dense, expert_layer) == (18_874_624, 37_748_736, 56_885_248)
+    layers = 6 * (norms + attn + gate) + 2 * dense + 4 * expert_layer
     embed_and_head = 2 * 200192 * d                                    # untied
-    assert counts.lm_params(TRINITY_CUT) == layers + embed_and_head + d == 1_235_316_224
+    assert counts.lm_params(TRINITY_CUT) == layers + embed_and_head + d == 1_286_655_488
     tied = dict(TRINITY_CUT, tie_word_embeddings=True)
     assert counts.lm_params(TRINITY_CUT) - counts.lm_params(tied) == 200192 * d
     biased = dict(TRINITY_CUT, attention_bias=True)
     assert counts.lm_params(biased) - counts.lm_params(TRINITY_CUT) == 6 * (32 + 2 * 4) * 128
+    # a qwen3 block of the same sizes: no gate, two norms a layer
+    qwen3_block = dict(TRINITY_CUT, model_type="qwen3")
+    assert counts.lm_params(TRINITY_CUT) - counts.lm_params(qwen3_block) == 6 * (gate + 2 * d)
+    assert (counts.lm_matmul_params(TRINITY_CUT) - counts.lm_matmul_params(qwen3_block)
+            == 6 * gate)
+
+
+def test_the_planned_trinity_mini_cut_by_hand():
+    d, vocab = 2048, 25024
+    attn = d * 32 * 128 + 2 * d * 4 * 128 + 32 * 128 * d + d * 32 * 128   # q, k, v, o, gate
+    dense = 3 * d * 6144
+    held = d * 128 + 16 * 3 * d * 1024 + 3 * d * 1024        # router, 16 experts, shared
+    params = 6 * (4 * d + attn + 2 * 128) + 2 * dense + 4 * held + 2 * vocab * d + d
+    assert counts.lm_params(TRINITY_MINI) == params == 770_493_952
+    # the top 8 at the held share 16 / 128
+    routed = d * 128 + 8 * 3 * d * 1024 * 16 // 128 + 3 * d * 1024
+    matmul = 6 * attn + 2 * dense + 4 * routed + d * vocab
+    assert counts.lm_matmul_params(TRINITY_MINI) == matmul == 341_704_704
+    # at 4,096 a window of 2,048 sees 2048 - 2048^2 / 8192 = 1,536 keys a query
+    attn_4k = 2 * 32 * 128 * 4096 + 5 * 4 * 32 * 128 * 1536
+    train_4k = counts.lm_train_flops_per_token(TRINITY_MINI, 4096)
+    assert train_4k == 3 * (2 * matmul + attn_4k) == 2_528_378_880
+    assert counts.lm_train_flops_per_token(TRINITY_MINI, 8192) == 2_691_956_736
+
+
+@pytest.mark.parametrize("kind", [None, "deepseek_v3", "../configs/qwen3-0.6b"],
+                         ids=["absent", "unknown", "path"])
+def test_a_block_the_counts_do_not_know_is_an_error(kind):
+    c = dict(TRINITY_CUT, model_type=kind)
+    for count in (counts.lm_params, counts.lm_matmul_params):
+        with pytest.raises(ValueError, match=f"model_type {kind!r}"):
+            count(c)
+
+
+def test_a_block_file_adds_an_architecture(tmp_path, monkeypatch):
+    blocks = tmp_path / "blocks"
+    shutil.copytree(counts.BLOCKS_DIR, blocks)
+    (blocks / "qwen3_moe.json").write_text((blocks / "qwen3.json").read_text())
+    (blocks / "sandwich.json").write_text(json.dumps(
+        {"source": "a test", "counted": {"qk_norm": True, "sandwich_norm": True},
+         "program": {}}))
+    monkeypatch.setattr(counts, "BLOCKS_DIR", str(blocks))
+    qwen3_moe = dict(TRINITY_CUT, model_type="qwen3_moe")
+    assert counts.lm_params(qwen3_moe) == counts.lm_params(dict(TRINITY_CUT, model_type="qwen3"))
+    with pytest.raises(ValueError, match="blocks/sandwich.json: the counts know no trait "
+                                         "\\['sandwich_norm'\\]"):
+        counts.lm_params(dict(TRINITY_CUT, model_type="sandwich"))
+
+
+@pytest.mark.parametrize("name", sorted(os.listdir(counts.BLOCKS_DIR)))
+def test_each_block_file_states_its_source_and_traits(name):
+    body = catalog.load_json(os.path.join(counts.BLOCKS_DIR, name))
+    assert name.endswith(".json") and set(body) == {"source", "counted", "program"}
+    assert set(body["counted"]) <= set(counts.COUNTED)
+    assert all(isinstance(v, bool) for v in body["counted"].values())
+    assert not set(body["program"]) & set(counts.COUNTED)
 
 
 def test_routed_work_scales_with_the_experts_held():
