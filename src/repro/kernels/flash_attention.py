@@ -1,12 +1,21 @@
 """Pallas TPU flash-attention kernels (q-blocked causal/windowed GQA): the
 forward, and the two backward kernels of its VJP.
 
-Forward: grid = (B * H, ceil(T / BLOCK_Q)). Each program holds one BLOCK_Q x
-hd query tile in VMEM plus its kv-head's full (S, hd) K and V slabs (VMEM
-budget = 2*S*hd*4 bytes; S<=2048 tiles at hd=128 are ~2 MiB). The MXU sees
-(BLOCK_Q, hd) @ (hd, S) and (BLOCK_Q, S) @ (S, hd) matmuls — both
-lane-aligned for hd, S multiples of 128. Run as the forward of the VJP
-(`return_lse=True`) it also writes each row's logsumexp.
+Forward (`flash_attention`): grid (B * H, T / BLOCK_Q).  Each program holds
+one BLOCK_Q x hd query tile and its KV head's whole (S, hd) K and V slabs
+in VMEM (2 * S * hd * 2 bytes in bf16: 4 MiB at S 8,192, hd 128, twice that
+double-buffered), and loops over the key blocks of BLOCK_K rows that the
+query block sees, keeping an f32 online softmax per row (running max m, row
+sum l, accumulator).  The loop bounds (`_key_blocks`, shared with the
+backward dq kernel): hi = min(cdiv(S, BLOCK_K), last_q // BLOCK_K + 1)
+under `causal`, lo = max(first_q - window + 1, 0) // BLOCK_K under
+`window`, every block otherwise.  `scored_block_share` counts the blocks
+scored: at T = S = 2,048, 62.5% in 256 x 512 blocks (136 of 256 in 128 x
+128).  Every block scored is masked.  Operands are upcast to f32.  Run as
+the forward of the VJP (`return_lse=True`) it also writes each row's
+logsumexp.  Tiles: DEFAULT_BLOCK_Q x DEFAULT_BLOCK_K = 256 x 512, the
+fastest of {128, 256, 512}^2 in a chip sweep at T = S = 2,048, 16/8 heads,
+hd 128 (PERF.md).
 
 Backward (`flash_attention_bwd`): both kernels recompute
 P = exp(Q K^T * scale - lse) block by block from the saved logsumexp, and
@@ -36,7 +45,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_Q = 128
+# the forward's query and key blocks (chip sweep at T=S=2048, hd 128: PERF.md)
+DEFAULT_BLOCK_Q = 256
+DEFAULT_BLOCK_K = 512
 # the backward's query and KV blocks (chip sweep at T=S=2048, hd 128: PERF.md)
 BWD_BLOCK_Q = 512
 BWD_BLOCK_K = 512
@@ -69,23 +80,76 @@ def _dot_t(a, b):
                                preferred_element_type=jnp.float32)
 
 
+def _key_blocks(first_q, last_q, *, causal: bool, window: int | None, seq_len: int,
+                block_k: int):
+    """The key blocks [lo, hi) that query rows first_q..last_q see: every
+    block that holds a key < `seq_len` the causal mask and `window` leave
+    to one of the rows.  Takes ints or traced int32 scalars."""
+    lo, hi = 0, pl.cdiv(seq_len, block_k)
+    if causal:
+        hi = jnp.minimum(hi, last_q // block_k + 1)
+    if window is not None:
+        first_k = jnp.maximum(first_q - window + 1, 0)
+        lo = jnp.where(first_k < seq_len, first_k // block_k, hi)
+    return lo, hi
+
+
+def _fwd_blocks(T: int, S: int, block_q: int, block_k: int):
+    """The forward's blocks: each cut to its sequence rounded up to 128."""
+    return min(block_q, pl.cdiv(T, 128) * 128), min(block_k, pl.cdiv(S, 128) * 128)
+
+
+def scored_block_share(T: int, S: int, *, causal: bool = True, window: int | None = None,
+                       block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K) -> float:
+    """The share of a call's (query block, key block) pairs that the forward
+    scores: those holding at least one (q, k) pair the mask leaves, with
+    query rows < T.  The kernel's own loop bounds (`_key_blocks`)."""
+    block_q, block_k = _fwd_blocks(T, S, block_q, block_k)
+    scored = 0
+    for first in range(0, T, block_q):
+        lo, hi = _key_blocks(first, min(first + block_q, T) - 1, causal=causal,
+                             window=window, seq_len=S, block_k=block_k)
+        scored += int(hi) - int(lo)
+    return scored / (pl.cdiv(T, block_q) * pl.cdiv(S, block_k))
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, scale: float, causal: bool,
-                  window: int | None, seq_len: int, block_q: int):
-    iq = pl.program_id(1)
+                  window: int | None, seq_len: int, q_len: int, block_q: int, block_k: int):
+    first = pl.program_id(1) * block_q
+    last = jnp.minimum(first + block_q, q_len) - 1  # rows past T are not scored
+    lo, hi = _key_blocks(first, last, causal=causal, window=window, seq_len=seq_len,
+                         block_k=block_k)
     q = q_ref[...].astype(jnp.float32) * scale      # (bq, hd)
-    k = k_ref[...].astype(jnp.float32)              # (S, hd)
-    v = v_ref[...].astype(jnp.float32)              # (S, hd)
-    s = q @ k.T                                     # (bq, S)
-    q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(_mask(q_pos, k_pos, causal=causal, window=window, seq_len=seq_len),
-                  s, NEG_INF)
-    m = jnp.max(s, axis=1, keepdims=True)
-    p = jnp.exp(s - m)
-    l = jnp.sum(p, axis=1, keepdims=True)  # noqa: E741 — flash-attn's row-sum name
-    o_ref[...] = ((p @ v) / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    q_pos = first + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+
+    def body(j, carry):
+        m, l, acc = carry  # noqa: E741 — flash-attn's row-sum name
+        start = pl.multiple_of(j * block_k, block_k)
+        k = k_ref[pl.ds(start, block_k), :].astype(jnp.float32)
+        v = v_ref[pl.ds(start, block_k), :].astype(jnp.float32)
+        s = _dot_t(q, k)                            # (bq, bk)
+        # every block is masked: scoring the blocks inside the mask without
+        # it, in a loop of their own, measured slower on the chip (PERF.md)
+        k_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(_mask(q_pos, k_pos, causal=causal, window=window, seq_len=seq_len),
+                      s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        # A row that sees no key of an early block keeps m = NEG_INF and
+        # gathers p = 1 per key; its first real score makes alpha
+        # exp(NEG_INF - m_new) = 0, which wipes that from l and acc.
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        return (m_new, alpha * l + jnp.sum(p, axis=1, keepdims=True),
+                alpha * acc + jnp.dot(p, v, preferred_element_type=jnp.float32))
+
+    m, l, acc = jax.lax.fori_loop(  # noqa: E741
+        lo, hi, body, (jnp.full((block_q, 1), NEG_INF, jnp.float32),
+                       jnp.zeros((block_q, 1), jnp.float32),
+                       jnp.zeros(q.shape, jnp.float32)))
+    l = jnp.maximum(l, 1e-30)  # noqa: E741
+    o_ref[...] = (acc / l).astype(o_ref.dtype)
     if lse_ref:
-        lse_ref[0][...] = _row(m + jnp.log(jnp.maximum(l, 1e-30)))
+        lse_ref[0][...] = _row(m + jnp.log(l))
 
 
 def _interpret() -> bool:
@@ -107,11 +171,14 @@ def _heads_last(x, B: int, L: int):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "window", "block_q", "return_lse")
+    jax.jit, static_argnames=("causal", "window", "block_q", "block_k", "return_lse")
 )
 def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
-                    block_q: int = DEFAULT_BLOCK_Q, return_lse: bool = False):
-    """q (B,T,H,hd); k/v (B,S,Hkv,hd) -> (B,T,H,hd). S padded to 128 inside.
+                    block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
+                    return_lse: bool = False):
+    """q (B,T,H,hd); k/v (B,S,Hkv,hd) -> (B,T,H,hd).  A block is cut to its
+    sequence rounded up to 128; T is padded to `block_q` and S to `block_k`
+    inside.
 
     With `return_lse` also each row's logsumexp of the scaled, masked scores,
     (B, H, T) f32: what `flash_attention_bwd` recomputes P from."""
@@ -120,16 +187,15 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
     assert H % Hkv == 0
     g = H // Hkv
     scale = 1.0 / math.sqrt(hd)
-
-    pad_t = (-T) % block_q
-    pad_s = (-S) % 128
+    block_q, block_k = _fwd_blocks(T, S, block_q, block_k)
+    pad_t, pad_s = (-T) % block_q, (-S) % block_k
     Tp, Sp = T + pad_t, S + pad_s
     qh, kh, vh = _heads_first(q, pad_t), _heads_first(k, pad_s), _heads_first(v, pad_s)
 
     grid = (B * H, Tp // block_q)
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, window=window,
-        seq_len=S, block_q=block_q,
+        seq_len=S, q_len=T, block_q=block_q, block_k=block_k,
     )
     o_spec = pl.BlockSpec((None, block_q, hd), lambda bh, iq: (bh, iq, 0))
     o_shape = jax.ShapeDtypeStruct((B * H, Tp, hd), q.dtype)
@@ -170,12 +236,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
     iq = pl.program_id(1)
     q, do = q_ref[...], do_ref[...]                 # (bq, hd)
     lse, delta = _col(lse_ref[...]), _col(delta_ref[...])  # (bq, 1)
-    first, last = iq * block_q, iq * block_q + block_q - 1
-    lo, hi = 0, pl.cdiv(seq_len, block_k)
-    if causal:
-        hi = jnp.minimum(hi, last // block_k + 1)
-    if window is not None:
-        lo = jnp.maximum(first - window + 1, 0) // block_k
+    first = iq * block_q
+    lo, hi = _key_blocks(first, first + block_q - 1, causal=causal, window=window,
+                         seq_len=seq_len, block_k=block_k)
     q_pos = first + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
 
     def body(j, acc):

@@ -4,33 +4,75 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.flash_attention import flash_attention, flash_attention_bwd
+from repro.kernels.flash_attention import (flash_attention, flash_attention_bwd,
+                                          scored_block_share)
 from repro.models.attention import _flash_attention_ad, blockwise_attention
 
 
+# the forward's tiles: as the default cuts them, and smaller and unequal
+# both ways, so that T and S are multiples of neither block
+BLOCKS = [dict(block_q=64), dict(block_q=64, block_k=32), dict(block_q=32, block_k=64)]
+BLOCK_IDS = ["64-default", "64-32", "32-64"]
+
+
+def _lse_oracle(q, k, *, causal, window):
+    """Each row's logsumexp of the scaled, masked scores, (B, H, T), from the
+    whole (T, S) score matrix."""
+    B, T, H, hd = q.shape
+    S, Hkv = k.shape[1], k.shape[2]
+    k = jnp.repeat(k, H // Hkv, axis=2)
+    s = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32), k.astype(jnp.float32),
+                   precision="highest") / np.sqrt(hd)
+    q_pos, k_pos = jnp.arange(T)[:, None], jnp.arange(S)[None]
+    mask = jnp.ones((T, S), bool)
+    if causal:
+        mask &= k_pos <= q_pos
+    if window is not None:
+        mask &= k_pos > q_pos - window
+    return jax.nn.logsumexp(jnp.where(mask, s, -jnp.inf), axis=-1)
+
+
+def _check_forward(q, k, v, *, causal=True, window=None, **blocks):
+    """The forward, with and without its logsumexp, against the blockwise
+    oracle's output and the dense logsumexp."""
+    want = np.asarray(blockwise_attention(q, k, v, causal=causal, window=window, kv_block=64))
+    out = flash_attention(q, k, v, causal=causal, window=window, **blocks)
+    np.testing.assert_allclose(np.asarray(out), want, atol=3e-5)
+    out, lse = flash_attention(q, k, v, causal=causal, window=window, return_lse=True, **blocks)
+    np.testing.assert_allclose(np.asarray(out), want, atol=3e-5)
+    assert lse.shape == (q.shape[0], q.shape[2], q.shape[1]) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(lse),
+                               np.asarray(_lse_oracle(q, k, causal=causal, window=window)),
+                               atol=3e-5)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("blocks", BLOCKS, ids=BLOCK_IDS)
 @pytest.mark.parametrize("T,S", [(128, 128), (64, 256), (200, 200)])
 @pytest.mark.parametrize("H,Hkv", [(4, 4), (8, 2)])
-def test_flash_matches_blockwise(T, S, H, Hkv):
+def test_flash_matches_blockwise(T, S, H, Hkv, blocks, causal):
     key = jax.random.PRNGKey(T + S + H)
     B, hd = 2, 32
     q = jax.random.normal(key, (B, T, H, hd), jnp.float32)
     k = jax.random.normal(jax.random.fold_in(key, 1), (B, S, Hkv, hd), jnp.float32)
     v = jax.random.normal(jax.random.fold_in(key, 2), (B, S, Hkv, hd), jnp.float32)
-    out = flash_attention(q, k, v, causal=True, block_q=64)
-    ref = blockwise_attention(q, k, v, causal=True, kv_block=64)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
+    _check_forward(q, k, v, causal=causal, **blocks)
 
 
-@pytest.mark.parametrize("window", [16, 64])
-def test_flash_sliding_window(window):
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("blocks", BLOCKS, ids=BLOCK_IDS)
+@pytest.mark.parametrize("window", [1, 16, 64, 100, 192, 300])
+def test_flash_sliding_window(window, blocks, causal):
+    """Windows of one key, under, at and over `block_q`, and of S or more.
+    With 64-row query blocks over 32-key blocks, a window under 64 leaves
+    rows that see no key of the first block scored: they must not leak
+    into the row sums."""
     key = jax.random.PRNGKey(7)
-    B, T, H, hd = 1, 192, 2, 32
+    B, T, H, Hkv, hd = 1, 192, 2, 1, 32
     q = jax.random.normal(key, (B, T, H, hd), jnp.float32)
-    k = jax.random.normal(jax.random.fold_in(key, 1), (B, T, H, hd), jnp.float32)
-    v = jax.random.normal(jax.random.fold_in(key, 2), (B, T, H, hd), jnp.float32)
-    out = flash_attention(q, k, v, causal=True, window=window, block_q=64)
-    ref = blockwise_attention(q, k, v, causal=True, window=window, kv_block=64)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
+    k = jax.random.normal(jax.random.fold_in(key, 1), (B, T, Hkv, hd), jnp.float32)
+    v = jax.random.normal(jax.random.fold_in(key, 2), (B, T, Hkv, hd), jnp.float32)
+    _check_forward(q, k, v, causal=causal, window=window, **blocks)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -49,15 +91,48 @@ def test_flash_dtypes(dtype):
     )
 
 
-def test_flash_nonaligned_shapes_padded():
+@pytest.mark.parametrize("blocks", BLOCKS, ids=BLOCK_IDS)
+def test_flash_nonaligned_shapes_padded(blocks):
     key = jax.random.PRNGKey(2)
     B, T, S, H, hd = 1, 50, 77, 2, 32  # neither T nor S aligned
     q = jax.random.normal(key, (B, T, H, hd), jnp.float32)
     k = jax.random.normal(jax.random.fold_in(key, 1), (B, S, H, hd), jnp.float32)
     v = jax.random.normal(jax.random.fold_in(key, 2), (B, S, H, hd), jnp.float32)
-    out = flash_attention(q, k, v, causal=True, block_q=64)
-    ref = blockwise_attention(q, k, v, causal=True, kv_block=64)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
+    _check_forward(q, k, v, **blocks)
+
+
+def _scored_by_brute_force(T, S, block_q, block_k, causal, window):
+    """The share of (query block, key block) pairs, over query rows < T,
+    that hold at least one (q, k) pair the mask leaves."""
+    q_pos, k_pos = np.arange(T)[:, None], np.arange(S)[None]
+    mask = np.ones((T, S), bool)
+    if causal:
+        mask &= k_pos <= q_pos
+    if window is not None:
+        mask &= k_pos > q_pos - window
+    nq, nk = -(-T // block_q), -(-S // block_k)
+    tiles = np.zeros((nq * block_q, nk * block_k), bool)
+    tiles[:T, :S] = mask
+    return tiles.reshape(nq, block_q, nk, block_k).any(axis=(1, 3)).sum() / (nq * nk)
+
+
+def test_scored_block_share_at_the_cell():
+    """T = S = 2,048 in 128 x 128 blocks: the causal mask leaves 136 of 256."""
+    assert scored_block_share(2048, 2048, block_q=128, block_k=128) == 136 / 256
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("window", [None, 1, 100, 128, 700, 5000])
+@pytest.mark.parametrize("T,S", [(2048, 2048), (300, 1000), (1000, 300), (77, 50)])
+def test_scored_block_share_matches_brute_force(T, S, window, causal):
+    for block_q in (64, 128, 256, 512):
+        for block_k in (64, 128, 256, 512):
+            got = scored_block_share(T, S, causal=causal, window=window,
+                                     block_q=block_q, block_k=block_k)
+            # the kernel cuts each block to its sequence rounded up to 128
+            bq, bk = min(block_q, -(-T // 128) * 128), min(block_k, -(-S // 128) * 128)
+            assert got == _scored_by_brute_force(T, S, bq, bk, causal, window), (block_q, block_k)
+
 
 
 # --------------------------------------------------------------------------

@@ -91,13 +91,18 @@ def test_vmapped_encode_compiles(one_chip, on_tpu):
     assert CUSTOM_CALL in hlo
 
 
-@pytest.mark.parametrize("seq", [128, 2048])
-def test_flash_attention_compiles(one_chip, on_tpu, seq):
-    """qwen3 heads: 16 query heads, 8 KV heads, head_dim 128, bf16."""
+@pytest.mark.parametrize("return_lse", [False, True], ids=["out", "lse"])
+@pytest.mark.parametrize("seq,window", [(128, None), (2048, None), (8192, 2048)])
+def test_flash_attention_compiles(one_chip, on_tpu, seq, window, return_lse):
+    """qwen3 heads: 16 query heads, 8 KV heads, head_dim 128, bf16, at the
+    forward's default tiles; at 8,192 tokens under a 2,048-key window the
+    tiled key loop holds whole K and V slabs of 8,192 rows in VMEM."""
     q = jax.ShapeDtypeStruct((1, seq, 16, 128), jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((1, seq, 8, 128), jnp.bfloat16, sharding=one_chip)
-    hlo = _compiled(lambda q, k, v: fa.flash_attention(q, k, v), q, kv, kv)
+    hlo = _compiled(lambda q, k, v: fa.flash_attention(q, k, v, window=window,
+                                                       return_lse=return_lse), q, kv, kv)
     assert CUSTOM_CALL in hlo
+    assert "%flash_attention" in hlo
 
 
 @pytest.mark.parametrize("seq", [128, 2048])
