@@ -16,8 +16,8 @@ Method, per scenario:
 
 The async PS baselines (FedBuff FedAvg, two-tier Hier) run as context arms.
 The derived field of each `asyncfl/<scenario>-fedchs_async` row carries
-``<x>x_vs_sync_t2gamma``; `run.py --json` gates on async beating sync in at
-least one scenario.
+``<x>x_vs_sync_t2gamma``; the suite fails unless async beats sync in at
+least one scenario.  The times are simulated, so the check reads no clock.
 """
 from __future__ import annotations
 
@@ -124,4 +124,7 @@ def run(quick: bool = True):
 
     rows.append(("asyncfl/scenarios-won", float(wins),
                  f"async_beats_sync_in_{wins}_of_{len(SCENARIOS)}"))
+    if not wins:
+        raise SystemExit("asyncfl: async Fed-CHS beat sync in no scenario "
+                         "(simulated time-to-accuracy)")
     return rows

@@ -1,37 +1,34 @@
 """LM-scale suite: the memory-lean mixed-precision engine at LM client scale.
 
 What `client_microbatch` + `Precision` buy for federated LM pretraining,
-measured on the REAL compiled round function (XLA memory analysis, not a
+read from the REAL compiled round function (XLA memory analysis, not a
 model of it):
 
   * lmscale/peak_bytes_{vmapped,mb1} — compiled peak-live bytes (argument +
     temp + output - donated) of one delta-mode Fed-CHS round at n_clients=8,
-    all-clients-vmapped vs client_microbatch=1.  The mb1 row's derived field
-    is the gated ratio (`run.py --json` fails below 2.0x): scanning clients
-    through one training slot drops the per-client activation replicas from
-    O(n) to O(microbatch), which is the knob that lands a 0.6B-param client
-    on one host.
-  * lmscale/tokens_per_s_{toy,scaled} — end-to-end training throughput of
-    the memory-lean configuration (bf16 compute + f32 master + bf16 wire,
-    remat on the scaled arm) at toy and scaled-up dims, through the full
-    driver (staging, channel, ledger).  Informational: CPU tokens/s is not a
-    TPU claim; the rows exist so the trajectory is tracked per PR.
+    all-clients-vmapped vs client_microbatch=1.  The suite fails unless mb1
+    at least halves them (`GATE_PEAK`): scanning clients through one
+    training slot drops the per-client activation replicas from O(n) to
+    O(microbatch), which is the knob that lands a 0.6B-param client on one
+    host.
+  * lmscale/tokens_per_s_{toy,scaled} — XLA:CPU training throughput of the
+    memory-lean configuration (bf16 compute + f32 master + bf16 wire, remat
+    on the scaled arm) at toy and scaled-up dims, through the full driver.
+    Informational and not chip speed (that is benchmarks/chip/).
   * lmscale/dense_wire_bf16 — the wire half of the policy: the bf16 dense
-    uplink's exact `channel_wire_bits` vs the f32 dense message.  Gated to be
-    EXACTLY 2.00x ("_exact" suffix): the ledger prices the true payload, so
+    uplink's exact `channel_wire_bits` vs the f32 dense message.  The suite
+    fails unless it is EXACTLY 2.00x: the ledger prices the true payload, so
     the ratio is arithmetic, not measurement.
 
-Standalone usage (applies the gates itself, exits nonzero on regression —
-the CI lm-scale-smoke job runs exactly this):
+Standalone usage (CI's lm-scale-smoke job runs it through benchmarks/run.py):
 
   PYTHONPATH=src:. python benchmarks/fig_lm_scale.py [--quick]
 """
 from __future__ import annotations
 
-import sys
 import time
 
-GATE_PEAK = 2.0  # mb=1 must at least halve compiled peak-live bytes (run.py)
+GATE_PEAK = 2.0  # mb=1 must at least halve compiled peak-live bytes
 
 
 def _lm_task(*, d_model: int, layers: int, vocab: int, seq: int, batch: int,
@@ -161,6 +158,16 @@ def run(quick: bool = True):
                  f"{full / half:.2f}x_vs_f32_dense{exact}"))
     print(f"  dense wire: bf16 {half} bits vs f32 {full} bits "
           f"({full / half:.2f}x{exact})")
+
+    failures = []
+    if ratio < GATE_PEAK:
+        failures.append(f"lmscale/peak_bytes_mb1: {ratio:.2f}x < {GATE_PEAK:.2f}x "
+                        "peak reduction vs vmapped")
+    if full != 2 * half:
+        failures.append(f"lmscale/dense_wire_bf16: bf16 wire is not exactly half "
+                        f"the f32 dense message ({full} vs {half} bits)")
+    if failures:
+        raise SystemExit("; ".join(failures))
     return rows
 
 
@@ -176,19 +183,6 @@ def main() -> None:
     print("\nname,us_per_call,derived")
     for name, us, derived in rows:
         print(f"{name},{us:.1f},{derived}")
-    failures = []
-    for name, _us, derived in rows:
-        if name == "lmscale/peak_bytes_mb1":
-            s = float(derived.split("x")[0])
-            if s < GATE_PEAK:
-                failures.append(f"{name}: {s:.2f}x < {GATE_PEAK:.2f}x peak "
-                                "reduction vs vmapped")
-        if name == "lmscale/dense_wire_bf16" and not derived.endswith("_exact"):
-            failures.append(f"{name}: bf16 wire is not exactly half the f32 "
-                            f"dense message ({derived})")
-    if failures:
-        print("PERF REGRESSION: " + "; ".join(failures), file=sys.stderr)
-        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -1,10 +1,10 @@
 """Regenerate EXPERIMENTS.md §Dry-run and §Roofline from experiments/dryrun/*.json,
-the §Benchmarks table from BENCH_core.json (written by `benchmarks/run.py
---json`), the hand-authored §Perf log from experiments/perf_log.md, the
+the hand-authored §Perf log from experiments/perf_log.md, the
 §Participation table written by `benchmarks/fig_participation.py`
 (experiments/participation.md), and §Telemetry from
-experiments/obs/summary.json (written by `benchmarks/run.py --profile`).  Sections whose inputs are absent are
-omitted rather than rendered empty, and a malformed/partial suite output
+experiments/obs/summary.json (written by `benchmarks/run.py --profile`).
+Chip speed is not here: it is PERF_LEDGER.jsonl's (PERF.md).  Sections
+whose inputs are absent are omitted rather than rendered empty, and a malformed/partial suite output
 (e.g. an interrupted benchmark run) skips that section with a warning
 instead of aborting the whole regeneration.
 
@@ -15,7 +15,6 @@ from __future__ import annotations
 import glob
 import json
 import os
-import re
 import sys
 
 ROOT = os.path.join(os.path.dirname(__file__), "..")
@@ -23,7 +22,6 @@ DRYRUN = os.path.join(ROOT, "experiments", "dryrun")
 PERF_LOG = os.path.join(ROOT, "experiments", "perf_log.md")
 PARTICIPATION = os.path.join(ROOT, "experiments", "participation.md")
 OBS_SUMMARY = os.path.join(ROOT, "experiments", "obs", "summary.json")
-BENCH_JSON = os.path.join(ROOT, "BENCH_core.json")
 OUT = os.path.join(ROOT, "EXPERIMENTS.md")
 
 SHAPE_ORDER = {"train_4k": 0, "prefill_32k": 1, "decode_32k": 2, "long_500k": 3}
@@ -149,137 +147,6 @@ def bottleneck_notes(recs):
     return "\n".join(lines)
 
 
-def bench_section():
-    """§Benchmarks from BENCH_core.json (benchmarks/run.py --json)."""
-    if not os.path.exists(BENCH_JSON):
-        return ""
-    with open(BENCH_JSON) as f:
-        payload = json.load(f)
-    mode = "quick" if payload.get("quick", True) else "--full"
-    lines = [
-        "## §Benchmarks",
-        "",
-        f"Machine-readable results from `benchmarks/run.py --json` ({mode} "
-        "mode, 2-core CPU container; BENCH_core.json is also uploaded as a "
-        "CI artifact by the perf-smoke and kernels-smoke jobs, so the perf "
-        "trajectory is tracked across PRs).  `scanned_*` rows are the "
-        "whole-run `lax.scan` executor vs the looped driver / seed-style "
-        "loop at 200 rounds, steady-state.  The `kernel/qsgd_encode_*` rows' "
-        "`payload_B` is the byte size of the actual packed uint32 wire value "
-        "(+ f32 norm sidecar) and equals `QSGDChannel.message_bits(n) / 8` "
-        "exactly — the ledger charges what the wire weighs "
-        "(tests/test_ledger.py); `round/fed_chs_packed_qsgd` vs the "
-        "dense-code baseline is the gated round-throughput comparison.",
-        "",
-        "| suite | row | per-call | derived |",
-        "|---|---|---|---|",
-    ]
-    for suite, data in payload.get("suites", {}).items():
-        for row in data.get("rows", []):
-            us = row.get("us_per_call", 0.0)
-            per = f"{us / 1e3:.1f} ms" if us >= 1e3 else f"{us:.1f} µs"
-            lines.append(f"| {suite} | {row.get('name', '?')} | {per} | "
-                         f"{row.get('derived', '')} |")
-    return "\n".join(lines)
-
-
-def population_section():
-    """§Population scaling from BENCH_core.json's population suite
-    (benchmarks/run.py --only population --json under forced 8 host
-    devices): the device-mesh sharded round's gated parity ratio and the
-    per-device share of the staged client-axis batch stack."""
-    if not os.path.exists(BENCH_JSON):
-        return ""
-    with open(BENCH_JSON) as f:
-        payload = json.load(f)
-    rows = payload.get("suites", {}).get("population", {}).get("rows", [])
-    if not rows:
-        return ""
-    by_name = {r["name"]: r for r in rows}
-    sharded = by_name.get("population/fedavg_round_sharded", {})
-    if "fallback" in sharded.get("derived", ""):
-        return ""  # single-device run: no scaling numbers to report
-    ratio = sharded.get("derived", "?").split("x")[0]
-    lines = [
-        "## §Population scaling",
-        "",
-        "The device-mesh sharded round engine (`repro.sharding.fed`,"
-        " README §Population-scale sharding) on a 2×4 ('clusters',"
-        " 'clients') mesh of forced host devices, vs the identical"
-        " single-device run.  Sharing one physical core, the gated claim is"
-        f" **parity** — the sharded round ran at {ratio}x the unsharded one"
-        " (gate: 0.9x, `benchmarks/run.py --json` + the CI sharding-smoke"
-        " job) while staying bit-identical (tests/test_sharding_fed.py)."
-        "  The scaling win is the memory column: each device holds 1/D of"
-        " the staged client-axis batch stack — the population-proportional"
-        " allocation — so the max simulable population grows with mesh"
-        " size instead of capping at one device's memory.",
-        "",
-        "| row | per-call | derived |",
-        "|---|---|---|",
-    ]
-    for r in rows:
-        us = r.get("us_per_call", 0.0)
-        per = f"{us / 1e3:.1f} ms" if us >= 1e3 else f"{us:.1f} µs"
-        lines.append(f"| {r['name']} | {per} | {r.get('derived', '')} |")
-    staged = [r for r in rows
-              if r["name"].startswith("population/staged_batch_n")]
-    if staged:
-        m = re.search(r"per_device_B=(\d+)_of_(\d+)",
-                      staged[-1].get("derived", ""))
-        if m and int(m.group(1)):
-            per_dev, tot = int(m.group(1)), int(m.group(2))
-            lines += ["", f"Staged-batch headroom at the largest measured "
-                          f"population: {per_dev / 1e6:.2f} MB/device of "
-                          f"{tot / 1e6:.2f} MB global — "
-                          f"{tot / per_dev:.1f}x on 8 devices."]
-    return "\n".join(lines)
-
-
-def asyncfl_section():
-    """§Async federation from BENCH_core.json's asyncfl suite
-    (benchmarks/run.py --only asyncfl --json): async event-driven Fed-CHS
-    vs the synchronous barrier chain on simulated time-to-accuracy."""
-    if not os.path.exists(BENCH_JSON):
-        return ""
-    with open(BENCH_JSON) as f:
-        payload = json.load(f)
-    rows = payload.get("suites", {}).get("asyncfl", {}).get("rows", [])
-    if not rows:
-        return ""
-    headline = payload.get("asyncfl_headline", {})
-    speedups = sorted(
-        (h.get("speedup") for h in headline.values() if h.get("speedup")),
-        reverse=True)
-    best = f"{speedups[0]:.1f}x" if speedups else "?"
-    lines = [
-        "## §Async federation",
-        "",
-        "The event-driven federation service (`repro.async_fl`, README"
-        " §Async federation service) vs the synchronous barrier chain on"
-        " SIMULATED time-to-accuracy: per scenario, sync trains once and is"
-        " re-timed through the scenario's `NetworkModel`"
-        " (`repro.netsim.simulate_run`), while async actually executes under"
-        " that network + an availability trace — arrival times drive its"
-        " event loop, so waiting is something it *chooses* (quorum/deadline)"
-        " rather than suffers.  Under hard stragglers/churn the async chain"
-        f" reached the target accuracy up to **{best}** sooner in simulated"
-        " wall-clock (gate: >1x in at least one scenario, `benchmarks/run.py"
-        " --json` + the CI async-smoke job), while its full-quorum"
-        " arithmetic stays bit-identical to the sync driver and a killed"
-        " run resumes bit-identically from its continuous checkpoint"
-        " (tests/test_async_fl.py, tests/test_resume_parity.py).",
-        "",
-        "| row | per-call | derived |",
-        "|---|---|---|",
-    ]
-    for r in rows:
-        us = r.get("us_per_call", 0.0)
-        per = f"{us / 1e3:.1f} ms" if us >= 1e3 else f"{us:.1f} µs"
-        lines.append(f"| {r['name']} | {per} | {r.get('derived', '')} |")
-    return "\n".join(lines)
-
-
 def telemetry_section():
     """§Telemetry from experiments/obs/summary.json (benchmarks/run.py
     --profile): per-round tap aggregates, span wall-clocks, and the netsim
@@ -302,8 +169,7 @@ def telemetry_section():
         f"{net['makespan_s']} s; the reporting deadline dropped "
         f"{net['dropped_client_rounds']} client-rounds, saving "
         f"{net['dropped_mb']} MB of uplink.  Tapped runs stay bit-identical "
-        "to untapped ones (tests/test_engine_parity.py) and under the 10% "
-        "overhead gate (benchmarks/run.py --json).",
+        "to untapped ones (tests/test_engine_parity.py).",
         "",
         "| tap (per-round, run aggregate) | mean | max |",
         "|---|---|---|",
@@ -328,8 +194,6 @@ def main():
     sections = [
         "# EXPERIMENTS — Fed-CHS reproduction + multi-pod dry-run + roofline",
         "(generated by scripts/make_experiments_md.py from experiments/dryrun/*.json; "
-        "§Benchmarks and §Population scaling from BENCH_core.json, written by "
-        "`benchmarks/run.py --json`; "
         "§Perf from experiments/perf_log.md; §Participation from "
         "experiments/participation.md, written by `benchmarks/run.py --only "
         "participation`; §Telemetry from experiments/obs/summary.json, written "
@@ -342,8 +206,7 @@ def main():
     if recs:
         builders += [lambda: dryrun_section(recs), lambda: roofline_section(recs),
                      lambda: bottleneck_notes(recs)]
-    builders += [bench_section, population_section, asyncfl_section,
-                 telemetry_section,
+    builders += [telemetry_section,
                  lambda: _read(PARTICIPATION), lambda: _read(PERF_LOG)]
     for build in builders:
         try:

@@ -104,7 +104,7 @@ class DenseChannel:
     historical dense channel.  Setting ``wire_dtype`` (e.g. ``"bfloat16"``
     from a `core.precision.Precision` policy) makes the wire real: `compress`
     round-trips every leaf through that dtype IN-GRAPH (so the lossy cast is
-    part of the compiled round and `phase_bytes` sees the narrow tensors),
+    part of the compiled round and moves the narrow tensors),
     `encode`/`decode`/`wire_bits` expose the exact payload the honesty test
     measures, and `bits_per_param` is overridden to the dtype's width — the
     ledger prices what actually travels, so a bf16 wire halves every dense

@@ -216,9 +216,9 @@ def _grad_round_fn(model: FedModel, taps: bool = False,
     With `taps`, additionally returns the grad-mode tele dict (obs/taps.py).
     Telemetry variants are SEPARATE cache entries: the taps=False graph is
     the exact pre-telemetry round, so the obs=None fast path costs nothing.
-    `microbatch` bounds concurrent client forward/backward passes at
-    BIT-IDENTICAL output (`oracles.grad_phase`); grad mode is the
-    paper-literal f32 path, so there is no precision knob here."""
+    `microbatch` bounds concurrent client forward/backward passes
+    (`oracles.grad_phase`: exact at microbatch >= n, ≤1 ulp below it); grad
+    mode is the paper-literal f32 path, so there is no precision knob here."""
     phase = grad_phase(model, microbatch)
 
     def round_fn(params, batch, gammas, lrs):
@@ -265,8 +265,7 @@ def _delta_round_fn(model: FedModel, channel: Channel, opt: LocalOpt,
     Returns (params, opt_state, per-interaction mean losses (J,)); with
     `taps` also the per-round tele dict (a final-interaction snapshot — see
     `_scan_and_tap_last`).  The round phases are `jax.named_scope`-tagged
-    (metadata only — numerics are untouched) so
-    roofline.attribution.phase_bytes can bill a whole round.
+    (metadata only — numerics are untouched).
 
     `microbatch` routes the interaction through `_microbatched_cluster_step`
     (peak params/activations O(microbatch) instead of O(n) model copies;
@@ -351,15 +350,15 @@ def _microbatched_cluster_step(local_fn, channel: Channel, mb: int,
     sliced off before returning.
 
     Numerics contract (pinned by tests/test_engine_parity.py): per-client
-    local trajectories are BIT-IDENTICAL to the vmapped path (vmap width
-    does not change per-lane arithmetic) and group uplinks are keyed with
-    the clients' GLOBAL slot ids (`compress_uplinks(slots=...)`), so the
-    deltas entering aggregation are bit-equal too.  Only the aggregation
-    ORDER changes: `acc += einsum(gamma_group, delta_group)` vs one full
-    einsum — XLA may contract the two differently, so aggregated params
-    match to ≤1 ulp per interaction (exact when mb >= n: a single group's
-    einsum IS the full einsum).  Grad mode needs none of this caveat — see
-    `oracles.grad_phase`.
+    local trajectories compute the same math as the vmapped path, and group
+    uplinks are keyed with the clients' GLOBAL slot ids
+    (`compress_uplinks(slots=...)`), so each client draws the same dither.
+    Two things change the summation order: XLA:CPU folds the vmapped
+    clients into the shapes of the matmuls, so a GEMM of another width may
+    sum in another order, and the aggregation becomes `acc +=
+    einsum(gamma_group, delta_group)` instead of one full einsum.  So
+    aggregated params match to ≤1 ulp per interaction, and exactly when
+    mb >= n (a single group IS the full vmap and its einsum the full one).
 
     Under a `precision` policy the helper is also the mixed-precision hot
     path: params/batch/lr are cast to `precision.compute` once per
@@ -630,8 +629,8 @@ class RoundEngine:
 
     `client_microbatch` bounds how many client replicas train concurrently
     inside a round (None = the historical all-clients vmap): peak memory
-    drops from O(n) to O(microbatch) model copies — bit-identical in grad
-    mode, ≤1 ulp in delta modes (`_microbatched_cluster_step`).
+    drops from O(n) to O(microbatch) model copies — ≤1 ulp per interaction,
+    exact at microbatch >= n (`_microbatched_cluster_step`).
     `precision` is the mixed-precision policy (core/precision.py): clients
     compute in `precision.compute` while the engine's authoritative params
     and delta aggregation stay in `precision.master`; grad mode (the

@@ -96,8 +96,8 @@ class FedCHSConfig:
     client_microbatch: int | None = None   # engine memory knob: at most this
                                            # many client replicas train at once
                                            # (None = the all-clients vmap);
-                                           # grad mode stays bit-identical,
-                                           # delta modes <=1 ulp/interaction
+                                           # <=1 ulp/interaction, exact at
+                                           # client_microbatch >= n
     precision: Precision | None = None     # mixed-precision policy
                                            # (core/precision.py): bf16 client
                                            # compute, f32 master params at the

@@ -17,7 +17,7 @@ now all live here, built from two generic factories:
 The jitted classifier-signature wrappers below (`local_sgd`,
 `multi_client_local_sgd`, `cluster_sgd`) keep the historical
 ``(params, xs, ys, lrs)`` calling convention for the parity tests' reference
-loops and benchmarks/engine_speedup.py's seed-style arms.
+loops.
 """
 from __future__ import annotations
 
@@ -66,11 +66,14 @@ def grad_phase(model: FedModel, microbatch: int | None = None):
     once: the all-clients vmap becomes a `lax.scan` over ceil(n/microbatch)
     client groups (tail group padded with client-0 replicas, sliced off before
     aggregation).  The per-step gradient STACK (n, ...) is still materialized
-    — Eq. (5) aggregates all n gradients jointly, and feeding the stack to the
-    very same einsum is what keeps the microbatched path BIT-IDENTICAL to the
-    vmapped one (per-client grads are vmap-width-invariant; pinned by
-    tests/test_engine_parity.py) — but activation memory drops from O(n) to
-    O(microbatch) model evaluations, which is the dominant term for LMs."""
+    — Eq. (5) aggregates all n gradients jointly, and the stack feeds the
+    very same einsum — but activation memory drops from O(n) to
+    O(microbatch) model evaluations, which is the dominant term for LMs.
+    The per-client grads are not bit-invariant to the group width: XLA:CPU
+    folds the vmapped clients into the shapes of the matmuls, and a GEMM of
+    another shape may sum in another order (~1e-7 in a grad at width 1).
+    So params match the vmapped path to ≤1 ulp per step, and exactly at
+    microbatch >= n (pinned by tests/test_engine_parity.py)."""
     grad_fn = jax.vmap(jax.value_and_grad(model.loss), in_axes=(None, 0))
 
     if microbatch is None:

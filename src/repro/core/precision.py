@@ -24,13 +24,12 @@ so only the ES keeps f32 state; grad mode (the paper-literal Eq. (5) path)
 ignores the policy entirely and the drivers' grad-mode gate excludes it.
 
 The engine tags its casts with `jax.named_scope`: "precision_cast" (going
-down) survives jit into compiled op_names, so
-`roofline.attribution.phase_bytes` bills the down-cast traffic directly.
-The up-cast ("master_accumulate") fuses into the gamma-weighted aggregate
-einsum, whose op_name carries the engine's "intra_agg" scope — so the
-accumulate cost of a mixed-precision round is billed there (the fused
-aggregate reads bf16 and writes f32); see
-tests/test_attribution.py::test_phase_bytes_attributes_mixed_precision_round.
+down) survives jit into compiled op_names (tests/test_obs.py), so a device
+trace attributes the down-cast traffic directly.  The up-cast
+("master_accumulate") fuses into the gamma-weighted aggregate einsum, whose
+op_name carries the engine's "intra_agg" scope — so the accumulate cost of
+a mixed-precision round lands there (the fused aggregate reads bf16 and
+writes f32).
 """
 from __future__ import annotations
 

@@ -136,8 +136,8 @@ def qsgd_encode(v: jnp.ndarray, key: jax.Array, *, s: int = 16, block: int = DEF
     block boundaries never depend on anything outside this leaf, so stacking,
     padding, or concatenating messages cannot shift them.
     """
-    # named_scope tags every op with op_name=".../qsgd_encode/..." so
-    # roofline.attribution.phase_bytes can bill the quantize+pack cost
+    # named_scope tags every op with op_name=".../qsgd_encode/..." so a
+    # device trace attributes the quantize+pack cost to the encode
     with jax.named_scope("qsgd_encode"):
         n = v.size
         nb = _leaf_blocks(n, block)

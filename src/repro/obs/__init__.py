@@ -59,8 +59,7 @@ class RunTelemetry:
                   the chunk's real device execution (accurate `--profile`
                   timelines).  False (default) defers materialization to
                   first read, keeping the scanned driver's async-dispatch
-                  pipelining — this is what keeps tapped runs inside the
-                  10% overhead gate (benchmarks/run.py --json).
+                  pipelining (the taps' cost on the chip is not measured).
     counts      — host-side counters the drivers bump at the same
                   boundaries as their spans (`count`): `staged_bytes`
                   (bytes of the staged scan inputs handed to the device)

@@ -56,8 +56,8 @@ def delta_taps(raw, applied, gammas, mask=None) -> dict[str, jax.Array]:
     the health signals toward 0).
 
     The taps run inside the scanned hot loop, so both the tensors they
-    read and every extra pass over the n×d client deltas are wall-clock
-    the 10% overhead gate (benchmarks/run.py --json) charges us for:
+    read and every extra pass over the n×d client deltas cost round time
+    (not measured on the chip):
 
     - the only per-client tree read is `raw` (materialised in the round
       regardless).  The channel output C(Δ_n) is deliberately NOT an

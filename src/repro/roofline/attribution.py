@@ -9,10 +9,6 @@ dominates, these helpers answer *which ops* are responsible (EXPERIMENTS.md
     61 times from .../shard_map/psum".
   * top_output_bytes — trip-scaled output bytes per instruction, skipping
     bookkeeping ops; a proxy for which tensors stream through HBM.
-  * phase_bytes — trip-scaled output bytes grouped by op_name pattern, e.g.
-    attributing the per-round quantize→pack cost to the `qsgd_encode` /
-    `qsgd_decode` named_scopes that kernels/ops.py wraps around the packed
-    wire transforms (benchmarks/kernels_micro.py reports these per round).
 
 All parse `compiled.as_text()` (post-optimization, post-SPMD HLO) so shapes
 are per-device.
@@ -91,37 +87,6 @@ def collective_breakdown(hlo_text: str, *, top: int = 20) -> list[dict]:
         for (op, shape, tag), b in sorted(agg.items(), key=lambda kv: -kv[1])
     ]
     return rows[:top]
-
-
-def phase_bytes(hlo_text: str, phases: dict[str, str]) -> dict[str, float]:
-    """Trip-scaled output bytes per *phase*, where a phase is a regex matched
-    against each instruction's op_name metadata (jax.named_scope tags land
-    there after jit).  Unmatched instructions are billed to "other"; ops
-    without op_name (bookkeeping fusions XLA synthesizes) too.
-
-    Example — attribute the packed-QSGD wire cost inside a scanned round::
-
-        phase_bytes(lowered.compile().as_text(),
-                    {"encode": r"qsgd_encode", "decode": r"qsgd_decode"})
-    """
-    pats = {name: re.compile(p) for name, p in phases.items()}
-    parsed = parse_hlo(hlo_text)
-    agg: dict[str, float] = defaultdict(float)
-
-    def on_instr(ins, mult):
-        if ins["op"] in _SKIP:
-            return
-        b = mult * _shape_bytes(ins["shape"])
-        m = _OPNAME_RE.search(ins["line"])
-        tag = m.group(1) if m else ""
-        for name, pat in pats.items():
-            if pat.search(tag):
-                agg[name] += b
-                return
-        agg["other"] += b
-
-    _walk(parsed["comps"], parsed["entry"], on_instr)
-    return dict(agg)
 
 
 def top_output_bytes(hlo_text: str, *, top: int = 25) -> list[dict]:

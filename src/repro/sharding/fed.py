@@ -22,7 +22,12 @@ backend caveat rides on top: XLA:CPU's batched-GEMM kernel choice can
 depend on the vmap lane count for LARGE layers under the thread-starved
 forced-host-device runtime (observed at 784x200, absent at <=128-wide
 layers and absent under the default runtime), which perturbs local grads
-at ~1e-7 before any of this module's collectives run.  The machinery
+at ~1e-7 before any of this module's collectives run.  A second one: in
+the unsharded body XLA:CPU may fuse a channel's decode (QSGD's
+dequantize) into the gamma-weighted dot as its operand, while here the
+all_gather materializes the decoded stack first, so the two dots may sum
+in different orders — FedAvg with QSGD(16) on 20 clients reads params
+within ~1 ulp (3.7e-9 after 4 rounds), not bit-equal.  The machinery
 itself is width-exact:
 
   * aggregation is NOT a `psum` of partial sums — that would reassociate

@@ -1,12 +1,8 @@
 """roofline.attribution on a hand-written post-optimization HLO module:
-trip scaling through while bodies, the 2x all-reduce factor, skip-list,
-and op_name-phase grouping (phase_bytes)."""
+trip scaling through while bodies, the 2x all-reduce factor and the
+skip-list; roofline.analysis's dtype-split dot FLOPs."""
 
-from repro.roofline.attribution import (
-    collective_breakdown,
-    phase_bytes,
-    top_output_bytes,
-)
+from repro.roofline.attribution import collective_breakdown, top_output_bytes
 
 # 8*4*4 = 128 B all-reduce inside a 48-trip while; 16*4 = 64 B permute outside
 HLO = """\
@@ -66,113 +62,6 @@ def test_top_output_bytes_scaling_and_skips():
     # the all-reduce output inside the loop is also trip-scaled
     ar = next(r for r in rows if r["name"] == "ar")
     assert ar["bytes"] == 128 * 48
-
-
-def test_phase_bytes_groups_by_op_name():
-    got = phase_bytes(HLO, {"comm": r"psum|ppermute"})
-    # tagged: in-loop all-reduce output (128 B x 48) + permute (64 B)
-    assert got["comm"] == 128 * 48 + 64
-    # untagged non-bookkeeping: the 16 KiB multiply x 48 (+ tiny cond pred)
-    assert got["other"] >= 64 * 64 * 4 * 48
-    # first-match-wins: a pattern hitting everything leaves nothing behind
-    all_in = phase_bytes(HLO, {"everything": r""})
-    assert "other" not in all_in or all_in["other"] == 0.0
-
-
-def test_phase_bytes_attributes_qsgd_wire_cost_end_to_end():
-    """The named_scope tags in kernels/ops.py survive jit into compiled HLO:
-    phase_bytes on a real encode→decode roundtrip bills nonzero bytes to both
-    phases. This is the hook benchmarks use to attribute quantize/pack cost."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels.ops import qsgd_decode, qsgd_encode
-
-    def roundtrip(v, key):
-        wire = qsgd_encode(v, key, s=16)
-        return qsgd_decode(wire, s=16, shape=(4096,))
-
-    hlo = (
-        jax.jit(roundtrip)
-        .lower(jnp.zeros((4096,)), jax.random.PRNGKey(0))
-        .compile()
-        .as_text()
-    )
-    got = phase_bytes(hlo, {"encode": r"qsgd_encode", "decode": r"qsgd_decode"})
-    assert got.get("encode", 0.0) > 0.0
-    assert got.get("decode", 0.0) > 0.0
-    # the payload itself (4 blocks x 6-bit planes x 32 words x 4 B) plus the
-    # uniform draw and intermediates: encode moves at least the payload bytes
-    assert got["encode"] >= 4 * 6 * 32 * 4
-
-
-def test_phase_bytes_pins_bf16_dense_wire_roundtrip():
-    """DenseChannel(wire_dtype="bfloat16") encode/decode: the wire scopes
-    survive jit and the billed bytes are EXACTLY the payload widths — encode
-    emits the bf16 payload (2 B/param), decode rebuilds f32 (4 B/param)."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.comm.channels import DenseChannel
-
-    ch = DenseChannel(wire_dtype="bfloat16")
-    leaf = jnp.zeros((1024,), jnp.float32)
-
-    enc_hlo = (
-        jax.jit(lambda t: ch.encode(t)).lower({"w": leaf}).compile().as_text()
-    )
-    got = phase_bytes(enc_hlo, {"encode": r"wire_encode"})
-    assert got["encode"] == 1024 * 2  # bf16 payload: 2 bytes per param
-
-    def dec(wires):
-        return ch.decode(wires, {"w": leaf})
-
-    dec_hlo = (
-        jax.jit(dec)
-        .lower([{"payload": leaf.astype(jnp.bfloat16)}])
-        .compile()
-        .as_text()
-    )
-    got = phase_bytes(dec_hlo, {"decode": r"wire_decode"})
-    assert got["decode"] == 1024 * 4  # rebuilt at f32: 4 bytes per param
-
-
-def test_phase_bytes_attributes_mixed_precision_round(small_task):
-    """A bf16 round bills nonzero bytes to precision_cast (the params/batch/lr
-    down-casts survive jit as tagged converts) on BOTH engine paths (vmapped
-    and microbatched).  The master up-cast of the deltas fuses into the
-    gamma-weighted einsum, so its bytes land under intra_agg — which must
-    therefore also be nonzero and larger than the bare f32 aggregate of the
-    no-precision round (the fused accumulate now reads bf16 and writes f32)."""
-    import jax.numpy as jnp
-
-    from repro.comm.channels import DenseChannel
-    from repro.core.engine import RoundEngine, _delta_round_fn
-    from repro.core.precision import Precision, dense_wire_channel
-
-    prec = Precision()
-    channel = dense_wire_channel(prec)
-    assert channel == DenseChannel(wire_dtype="bfloat16")
-    engine = RoundEngine(small_task.model, channel, precision=prec)
-    params = small_task.init_params()
-    n = len(small_task.cluster_members[0])
-    opt_state = engine.init_opt_state(params, n)
-    batch = small_task.sample_round_batches(0, 4, 2)
-    gammas = jnp.asarray(small_task.cluster_weights(0))
-    lrs = jnp.full((2, 2), 0.05, jnp.float32)
-    for mb in (None, 2):
-        fn = _delta_round_fn(engine.model, channel, engine.local_opt, False,
-                             mb, prec)
-        hlo = fn.lower(params, opt_state, batch, gammas, lrs,
-                       None).compile().as_text()
-        got = phase_bytes(hlo, {"cast": r"precision_cast",
-                                "agg": r"intra_agg|master_accumulate",
-                                "train": r"local_train"})
-        assert got.get("cast", 0.0) > 0.0, mb
-        assert got.get("agg", 0.0) > 0.0, mb
-        assert got.get("train", 0.0) > 0.0, mb
-        # training (fwd+bwd over E steps per client) still dominates
-        assert got["train"] > got["agg"], mb
 
 
 def test_compute_seconds_prices_f32_dots_at_half_rate():

@@ -707,10 +707,13 @@ if HAS_HYPOTHESIS:
 
 # --------------------------------------------------------------------------
 # client_microbatch parity: the group-scanned engine vs the all-clients vmap.
-# Grad mode is BIT-identical for every microbatch width (the per-step grad
-# stack feeds the same einsum); delta mode re-associates the gamma-weighted
-# aggregation (acc += einsum per group), so params/opt-state agree to <=1 ulp
-# of the aggregate (atol 3e-6 at MLP scale) and exactly at microbatch == n.
+# Both modes agree exactly at microbatch == n (one group is the full vmap).
+# Below it, grad mode feeds the same einsum, but XLA:CPU folds the vmapped
+# clients into the shapes of the matmuls, and a GEMM of another shape may sum
+# in another order (seen at width 1: ~1e-7 in a local grad), so params agree
+# to the same bound as delta mode.  Delta mode also re-associates the
+# gamma-weighted aggregation (acc += einsum per group), so params/opt-state
+# agree to <=1 ulp of the aggregate (atol 3e-6 at MLP scale).
 # --------------------------------------------------------------------------
 
 from repro.comm.channels import DenseChannel, QSGDChannel, SignSGDChannel  # noqa: E402
@@ -731,8 +734,16 @@ def test_microbatch_grad_mode_bit_parity(small_task):
         eng = RoundEngine(task.model, client_microbatch=mb)
         p_mb, l_mb = eng.grad_round(params, batch, gammas, lrs)
         for a, b in zip(jax.tree.leaves(p_ref), jax.tree.leaves(p_mb)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        np.testing.assert_array_equal(np.asarray(l_ref), np.asarray(l_mb))
+            if mb == n:  # one group: the very same vmap
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            else:
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                           rtol=0, atol=3e-6)
+        if mb == n:
+            np.testing.assert_array_equal(np.asarray(l_ref), np.asarray(l_mb))
+        else:
+            np.testing.assert_allclose(np.asarray(l_ref), np.asarray(l_mb),
+                                       rtol=0, atol=1e-6)
 
 
 def test_microbatch_delta_mode_one_ulp(small_task):

@@ -2,13 +2,17 @@
 export + validation, netsim drop surfacing, named_scope round attribution,
 and the RunResult empty-log metric-direction fix."""
 import json
+import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.comm.channels import DenseChannel, QSGDChannel
 from repro.core import FedCHSConfig, run_fed_chs
 from repro.core.ledger import CommLedger
+from repro.core.precision import Precision
 from repro.core.simulation import RunResult
 from repro.netsim import Timeline, edge_cloud_network, replay_run
 from repro.netsim.events import JobTimes
@@ -166,38 +170,52 @@ def test_sweep_rejects_telemetry(small_task):
 
 
 # --------------------------------------------------------------------------
-# named_scope round attribution: the engine's phase tags survive jit, so
-# roofline.attribution.phase_bytes can bill a WHOLE Fed-CHS round by phase
+# named_scope round attribution: the engine's and the channels' phase tags
+# survive jit into the compiled HLO's op_name metadata — the names the chip
+# benchmark's trace readers select device ops by (local_train_ms reads
+# `local_train`)
 # --------------------------------------------------------------------------
 
 
-def test_phase_bytes_attributes_delta_round(small_task):
-    from repro.core.engine import RoundEngine, _delta_round_fn, dummy_subs
-    from repro.roofline.attribution import phase_bytes
+def _compiled_scopes(lowered) -> set[str]:
+    """Every name in the compiled HLO's op_name paths; a scope traced under a
+    transform reads as e.g. `vmap(local_train)`, so the wrappers are split
+    off too."""
+    hlo = lowered.compile().as_text()
+    return {part for name in re.findall(r'op_name="([^"]+)"', hlo)
+            for part in re.split(r"[/()]", name)}
 
-    engine = RoundEngine(small_task.model)
+
+@pytest.mark.parametrize("microbatch,precision,channel", [
+    (None, None, DenseChannel()),
+    (1, Precision(), DenseChannel(wire_dtype="bfloat16")),
+], ids=["vmapped-f32", "microbatched-bf16"])
+def test_named_scopes_survive_jit_delta_round(small_task, microbatch, precision,
+                                              channel):
+    """The masked delta round — the body the scanned drivers trace, on both
+    client paths; the microbatched bf16 one is the chip benchmark's."""
+    from repro.core.engine import RoundEngine, _masked_delta_round_fn, dummy_subs
+
+    engine = RoundEngine(small_task.model, channel, precision=precision)
     params = small_task.init_params()
     n = len(small_task.cluster_members[0])
     opt_state = engine.init_opt_state(params, n)
     batch = small_task.sample_round_batches(0, 4, 2)
     gammas = jnp.asarray(small_task.cluster_weights(0))
     lrs = jnp.full((2, 2), 0.05, jnp.float32)
-    fn = _delta_round_fn(engine.model, engine.channel, engine.local_opt, False)
-    hlo = fn.lower(params, opt_state, batch, gammas, lrs,
-                   dummy_subs(2)).compile().as_text()
-    got = phase_bytes(hlo, {"local_train": r"local_train",
-                            "uplink": r"uplink",
-                            "intra_agg": r"intra_agg"})
-    assert got.get("local_train", 0.0) > 0.0
-    assert got.get("uplink", 0.0) > 0.0
-    assert got.get("intra_agg", 0.0) > 0.0
-    # local training (per-client fwd+bwd over E steps) dominates the round
-    assert got["local_train"] > got["intra_agg"]
+    fn = _masked_delta_round_fn(engine.model, channel, engine.local_opt, False,
+                                microbatch, precision)
+    scopes = _compiled_scopes(fn.lower(params, opt_state, batch, gammas,
+                                       jnp.ones((n,), jnp.float32), lrs,
+                                       dummy_subs(2)))
+    want = {"local_train", "uplink", "intra_agg"}
+    if precision is not None:
+        want.add("precision_cast")
+    assert want <= scopes, want - scopes
 
 
-def test_phase_bytes_attributes_multi_round_es_hop(small_task):
+def test_named_scopes_survive_jit_multi_round_es_hop(small_task):
     from repro.core.engine import RoundEngine, _multi_round_fn, dummy_subs
-    from repro.roofline.attribution import phase_bytes
 
     engine = RoundEngine(small_task.model)
     params = small_task.init_params()
@@ -211,14 +229,22 @@ def test_phase_bytes_attributes_multi_round_es_hop(small_task):
     lrs = jnp.full((2, 2), 0.05, jnp.float32)
     fn = _multi_round_fn(engine.model, engine.channel, engine.channel,
                          engine.local_opt, False)
-    hlo = fn.lower(params, opt_state, batch, gammas, mask, es_weights, lrs,
-                   dummy_subs(2, M), dummy_subs(M)).compile().as_text()
-    got = phase_bytes(hlo, {"local_train": r"local_train",
-                            "uplink": r"uplink",
-                            "intra_agg": r"intra_agg",
-                            "es_hop": r"es_hop"})
-    for phase in ("local_train", "uplink", "intra_agg", "es_hop"):
-        assert got.get(phase, 0.0) > 0.0, phase
+    scopes = _compiled_scopes(fn.lower(params, opt_state, batch, gammas, mask,
+                                       es_weights, lrs, dummy_subs(2, M),
+                                       dummy_subs(M)))
+    want = {"local_train", "uplink", "intra_agg", "es_hop"}
+    assert want <= scopes, want - scopes
+
+
+@pytest.mark.parametrize("channel,encode,decode", [
+    (QSGDChannel(16), "qsgd_encode", "qsgd_decode"),
+    (DenseChannel(wire_dtype="bfloat16"), "wire_encode", "wire_decode"),
+], ids=["qsgd16", "bf16-wire"])
+def test_named_scopes_survive_jit_channel_wire(channel, encode, decode):
+    leaf = {"w": jnp.zeros((4096,), jnp.float32)}
+    fn = jax.jit(lambda t, key: channel.decode(channel.encode(t, key), t))
+    scopes = _compiled_scopes(fn.lower(leaf, jax.random.PRNGKey(0)))
+    assert {encode, decode} <= scopes
 
 
 # --------------------------------------------------------------------------

